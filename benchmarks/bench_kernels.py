@@ -56,12 +56,12 @@ def main(smoke: bool = False):
     # paged attention
     n_slots, bt, mb = 64, 16, 8
     q1 = jnp.asarray(rng.normal(size=(4, h, d)).astype(np.float32))
-    kp = jnp.asarray(rng.normal(size=(n_slots, bt, kv, d)).astype(np.float32))
-    vp = jnp.asarray(rng.normal(size=(n_slots, bt, kv, d)).astype(np.float32))
+    kvp = jnp.asarray(rng.normal(size=(n_slots, 2, kv, bt, d))
+                      .astype(np.float32))
     tables = jnp.asarray(rng.integers(0, n_slots, (4, mb)), jnp.int32)
     lens = jnp.full((4,), bt * mb, jnp.int32)
-    us = timed(lambda: ops.paged_attention(q1, kp, vp, tables, lens))
-    us_ref = timed(lambda: ref.paged_attention(q1, kp, vp, tables, lens, bt))
+    us = timed(lambda: ops.paged_attention(q1, kvp, tables, lens))
+    us_ref = timed(lambda: ref.paged_attention(q1, kvp, tables, lens))
     emit("kernel_paged_attention", us,
          f"ref_us={us_ref:.0f};kv_kib={4*mb*bt*kv*d*2*4/1024:.0f}")
 

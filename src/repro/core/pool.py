@@ -29,6 +29,7 @@ signal the MIAD policy keeps below its target.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Dict, Tuple
 
 import jax
@@ -54,6 +55,15 @@ class PoolConfig:
     cold_sbs: int              # superblocks in the COLD region
     dtype: str = "float32"
     word_bytes: int = 4
+    # shape of one slot's row in `data` (its product is slot_words); ()
+    # keeps the flat [n_slots + 1, slot_words] heap. A client whose
+    # kernels read slots in place gives the tiled shape it reads them
+    # in (the paged KV pool: [2, KV, block_tokens, head_dim])
+    slot_shape: Tuple[int, ...] = ()
+
+    @property
+    def row_shape(self) -> Tuple[int, ...]:
+        return self.slot_shape or (self.slot_words,)
 
     @property
     def n_sbs(self) -> int:
@@ -94,7 +104,8 @@ class PoolConfig:
 def make_config(max_objects: int, slot_words: int, *, sb_slots: int = 64,
                 page_slots: int = 8, new_frac: float = 0.125,
                 hot_frac: float = 0.375, slack: float = 1.5,
-                dtype: str = "float32") -> PoolConfig:
+                dtype: str = "float32",
+                slot_shape: Tuple[int, ...] = ()) -> PoolConfig:
     """Size a pool with `slack`x physical slots over max_objects, split into
     NEW/HOT/COLD regions by fraction."""
     n_slots = int(max_objects * slack)
@@ -103,10 +114,14 @@ def make_config(max_objects: int, slot_words: int, *, sb_slots: int = 64,
     hot_sbs = max(1, int(n_sbs * hot_frac))
     cold_sbs = max(1, n_sbs - new_sbs - hot_sbs)
     word_bytes = jnp.dtype(dtype).itemsize
+    if slot_shape and math.prod(slot_shape) != slot_words:
+        raise ValueError(f"slot_shape {slot_shape} does not hold "
+                         f"{slot_words} words")
     return PoolConfig(max_objects=max_objects, slot_words=slot_words,
                       sb_slots=sb_slots, page_slots=page_slots,
                       new_sbs=new_sbs, hot_sbs=hot_sbs, cold_sbs=cold_sbs,
-                      dtype=dtype, word_bytes=word_bytes)
+                      dtype=dtype, word_bytes=word_bytes,
+                      slot_shape=tuple(slot_shape))
 
 
 def init(cfg: PoolConfig) -> Dict[str, jax.Array]:
@@ -129,7 +144,7 @@ def init(cfg: PoolConfig) -> Dict[str, jax.Array]:
     all slots."""
     free_q, free_head, free_count = fl.seed(cfg)
     return {
-        "data": jnp.zeros((cfg.n_slots + 1, cfg.slot_words),
+        "data": jnp.zeros((cfg.n_slots + 1,) + cfg.row_shape,
                           jnp.dtype(cfg.dtype)),
         "table": ot.make_table(cfg.max_objects),
         "slot_owner": jnp.full((cfg.n_slots,), -1, jnp.int32),
@@ -245,12 +260,15 @@ def apply_op(cfg: PoolConfig, state: Dict, op, obj_ids: jax.Array,
     # route to the scratch row and must write ZEROS — its invariant) ---
     d_mask = (is_write & live) | a_do
     d_slot = jnp.where(is_alloc, a_slot, slots)
+    k = obj_ids.shape[0]
+    rows = values.astype(state["data"].dtype).reshape((k,) + cfg.row_shape)
+    row_mask = d_mask.reshape((k,) + (1,) * len(cfg.row_shape))
     data = state["data"].at[jnp.where(d_mask, d_slot, cfg.n_slots)].set(
-        jnp.where(d_mask[:, None], values.astype(state["data"].dtype), 0),
-        mode="drop")
+        jnp.where(row_mask, rows, 0), mode="drop")
 
     # --- read output: gathered AFTER the (empty-on-read) scatter ---
-    vals = jnp.where((is_read & live)[:, None], data[slots], 0)
+    vals = jnp.where((is_read & live)[:, None],
+                     data[slots].reshape(k, cfg.slot_words), 0)
 
     # --- table: dereference access bits (+ATC when armed), alloc words,
     # free words. The alloc/free rewrites go through fresh K-scattered

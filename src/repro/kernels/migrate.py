@@ -4,8 +4,14 @@ The paper's hot loop when tidying: copy object payloads from their old
 slots to their new (dense) slots. On TPU this is a batched indirection
 copy through VMEM: move indices are *scalar-prefetched* so the index math
 runs ahead of the data DMAs (PrefetchScalarGridSpec), each grid step
-streams one [1, W_TILE] tile HBM->VMEM->HBM, and the pool array is
-aliased in/out so unmoved slots cost nothing.
+streams one whole slot HBM->VMEM->HBM, and the pool array is aliased
+in/out so unmoved slots cost nothing.
+
+Layout: the pool is [n_slots, *row] with row of rank >= 2, and a grid
+step's block is (1, *row): its last two dims are the array's own, which
+TPU's (8, 128) block rule always admits. A pool whose slots are
+tile-shaped (the paged KV pool's [2, KV, bt, D] rows) is read in place;
+the ops wrapper views a flat [n_slots, W] pool as [n_slots, 1, W].
 
 In-place safety contract (enforced by callers — ops.migrate routes
 masked-out moves to a scratch row to honor it): grid steps run in
@@ -19,14 +25,10 @@ destination, it rewrites stale bytes over the fresh copy.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-LANE = 128  # TPU lane width; slot payload is padded to a multiple
 
 
 def _kernel(idx_ref, data_ref, out_ref):
@@ -36,28 +38,24 @@ def _kernel(idx_ref, data_ref, out_ref):
 
 
 def migrate_pallas(data: jax.Array, src: jax.Array, dst: jax.Array,
-                   *, w_tile: int = 512, interpret: bool = True
-                   ) -> jax.Array:
-    """data: [n_slots, W] (W % 128 == 0), src/dst: [n_moves] int32.
-    Returns data with data[dst[i]] = data[src[i]] applied in move order;
-    each move reads its source's PRE-kernel value (see the module
+                   *, interpret: bool = True) -> jax.Array:
+    """data: [n_slots, *row] (row of rank >= 2), src/dst: [n_moves]
+    int32. Returns data with data[dst[i]] = data[src[i]] applied in move
+    order; each move reads its source's PRE-kernel value (see the module
     docstring for the aliasing contract).
     """
-    n_slots, w = data.shape
-    n_moves = src.shape[0]
-    assert w % LANE == 0, f"slot width {w} not lane-aligned"
-    w_tile = min(w_tile, w)
-    assert w % w_tile == 0
+    assert data.ndim >= 3, f"slot rows must have rank >= 2: {data.shape}"
+    row = data.shape[1:]
+    zeros = (0,) * len(row)
     idx = jnp.stack([src, dst], axis=0).astype(jnp.int32)  # [2, n_moves]
 
-    grid = (n_moves, w // w_tile)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
-        in_specs=[pl.BlockSpec((1, w_tile),
-                               lambda i, j, idx: (idx[0, i], j))],
-        out_specs=pl.BlockSpec((1, w_tile),
-                               lambda i, j, idx: (idx[1, i], j)),
+        grid=(src.shape[0],),
+        in_specs=[pl.BlockSpec((1,) + row,
+                               lambda i, idx: (idx[0, i],) + zeros)],
+        out_specs=pl.BlockSpec((1,) + row,
+                               lambda i, idx: (idx[1, i],) + zeros),
     )
     fn = pl.pallas_call(
         _kernel,
@@ -65,5 +63,6 @@ def migrate_pallas(data: jax.Array, src: jax.Array, dst: jax.Array,
         out_shape=jax.ShapeDtypeStruct(data.shape, data.dtype),
         input_output_aliases={1: 0},   # pool array aliased in/out
         interpret=interpret,
+        name="migrate",
     )
     return fn(idx, data)

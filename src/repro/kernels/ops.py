@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Each wrapper: validates/normalizes shapes (lane padding, GQA expansion),
-selects interpret mode (Pallas kernels execute in interpret mode on CPU —
-this container — and compile natively on TPU), and matches the ref.py
-oracle bit-for-bit on the unpadded region.
+Each wrapper: validates/normalizes shapes (lane padding, GQA grouping),
+selects interpret mode (Pallas kernels execute in interpret mode on CPU
+and compile natively on TPU), and matches the ref.py oracle bit-for-bit
+on the unpadded region.
 """
 from __future__ import annotations
 
@@ -48,12 +48,11 @@ def _tile(n: int, want: int) -> int:
 # ---------------------------------------------------------------------------
 # migrate
 # ---------------------------------------------------------------------------
-@functools.partial(jax.jit, static_argnames=("w_tile", "has_scratch_row"))
+@functools.partial(jax.jit, static_argnames=("has_scratch_row",))
 def migrate(data: jax.Array, src: jax.Array, dst: jax.Array,
-            ok: jax.Array, *, w_tile: int = 512,
-            has_scratch_row: bool = False) -> jax.Array:
-    """data: [n_slots(+1), W]; src/dst/ok: [n_moves]. Caller contract for
-    the ACTIVE moves: disjoint src/dst sets OR left-packing order (see
+            ok: jax.Array, *, has_scratch_row: bool = False) -> jax.Array:
+    """data: [n_slots(+1), *row]; src/dst/ok: [n_moves]. Caller contract
+    for the ACTIVE moves: disjoint src/dst sets OR left-packing order (see
     migrate.py). Masked moves (ok=False) are routed to a scratch row —
     NOT turned into self-copies, because a masked entry's slot may be an
     earlier move's destination, and a grid step reads the pre-kernel
@@ -62,25 +61,26 @@ def migrate(data: jax.Array, src: jax.Array, dst: jax.Array,
     `has_scratch_row=True` declares that the caller's pool layout already
     carries a permanent scratch row as data's LAST row (core/pool.py) —
     masked moves copy that row onto itself (a no-op for its all-zero
-    invariant) and NO whole-pool pad copy happens; on TPU with
-    lane-aligned slot widths the kernel aliases the pool in place. With
-    False (standalone use, kernel sweeps) a scratch row is appended,
-    which costs one pool copy per call."""
-    n, w = data.shape
+    invariant) and NO whole-pool pad copy happens; the kernel aliases
+    the pool in place. With False (standalone use, kernel sweeps) a
+    scratch row is appended, which costs one pool copy per call.
+
+    A flat [n, W] pool is viewed as [n, 1, W] for the kernel's block rule;
+    on TPU that view is a relayout copy, which a pool with tile-shaped
+    slot rows (`PoolConfig.slot_shape`) never pays."""
+    n = data.shape[0]
     if has_scratch_row:
         scratch = jnp.int32(n - 1)
-        padded = _pad_to(data, LANE, 1)
+        padded = data
     else:
         scratch = jnp.int32(n)
-        # one pad covers both the lane alignment and the scratch row (a
-        # second concatenate would copy the whole pool again)
-        padded = jnp.pad(data, ((0, 1), (0, (-w) % LANE)))
+        padded = jnp.pad(data, ((0, 1),) + ((0, 0),) * (data.ndim - 1))
+    view = padded if padded.ndim >= 3 else padded[:, None, :]
     src_eff = jnp.where(ok, src, scratch).astype(jnp.int32)
     dst_eff = jnp.where(ok, dst, scratch).astype(jnp.int32)
-    out = _mig.migrate_pallas(padded, src_eff, dst_eff,
-                              w_tile=_tile(padded.shape[1], w_tile),
+    out = _mig.migrate_pallas(view, src_eff, dst_eff,
                               interpret=_interpret())
-    return out[:n, :w]
+    return out.reshape(padded.shape)[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -92,20 +92,19 @@ def access_scan(table: jax.Array, ciw_threshold: jax.Array, *,
                 sb_slots: int, n_sbs: int, with_hist: bool = True):
     """table: [N] uint32. Returns (new_table, to_hot bool, to_cold bool,
     hist [n_sbs] int32 — zeros when with_hist=False, which statically
-    skips the one-hot contraction for callers that discard it,
+    skips the histogram for callers that discard it,
     skipped_atc [] int32 — the ATC-vetoed count, folded into the sweep so
     the collector's use_pallas path never re-reads table fields)."""
     n = table.shape[0]
-    padded = _pad_to(table, LANE, axis=0)  # pad words are FREE=0b? pad=0
-    # pad words decode as heap=NEW,slot=0,access=0 -> not live? heap 0 is
-    # NEW; guard: set pad words to FREE so they never classify.
+    # a zero pad word would decode as a live NEW object: pad with FREE
+    # words so padding never classifies
+    padded = _pad_to(table, LANE, axis=0)
     if padded.shape[0] != n:
         from repro.core import object_table as ot
-        pad_word = ot.free_word()
-        padded = padded.at[n:].set(pad_word)
+        padded = padded.at[n:].set(ot.free_word())
     new_t, to_hot, to_cold, hist, skipped = _scan.access_scan_pallas(
         padded, ciw_threshold, sb_slots, n_sbs,
-        rows_tile=_tile(padded.shape[0] // LANE, 64),
+        rows_tile=_tile(padded.shape[0] // LANE, 8 if with_hist else 64),
         with_hist=with_hist, interpret=_interpret())
     return (new_t[:n], to_hot[:n].astype(bool), to_cold[:n].astype(bool),
             hist, skipped)
@@ -145,24 +144,19 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # paged_attention
 # ---------------------------------------------------------------------------
 @jax.jit
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
+def paged_attention(q: jax.Array, kv_pages: jax.Array,
                     block_tables: jax.Array, seq_lens: jax.Array
                     ) -> Tuple[jax.Array, jax.Array]:
-    """q: [B,H,D]; k_pages/v_pages: [n_slots, bt, KV, D];
-    block_tables: [B, MB]; seq_lens: [B].
+    """q: [B,H,D]; kv_pages: [n_slots, 2, KV, bt, D] (the paged KV pool,
+    read in place); block_tables: [B, MB]; seq_lens: [B].
     Returns (out [B,H,D], touched [B,MB] bool)."""
     b, h, d = q.shape
-    kv = k_pages.shape[2]
-    rep = h // kv
-    qg = q.reshape(b, kv, rep, d)
-    qg = _pad_to(qg, LANE, 3)
-    kp = _pad_to(k_pages, LANE, 3)
-    vp = _pad_to(v_pages, LANE, 3)
+    kv = kv_pages.shape[2]
+    qg = q.reshape(b, kv, h // kv, d)
     out, touched = _pa.paged_attention_pallas(
-        qg, kp, vp, block_tables, seq_lens, scale=d ** -0.5,
+        qg, kv_pages, block_tables, seq_lens, scale=d ** -0.5,
         interpret=_interpret())
-    out = out[..., :d].reshape(b, h, d)
-    return out, touched.astype(bool)
+    return out.reshape(b, h, d), touched.astype(bool)
 
 
 # ---------------------------------------------------------------------------

@@ -13,6 +13,13 @@ tracking rides the read.
 
 GQA layout: q is [B, KV, REP, D] (q heads grouped by kv head); each grid
 step contracts the [bt, D] block against all REP q-heads of its kv head.
+
+Pool layout: one KV block is one pool slot of shape [2, KV, bt, D] (K/V
+first, then kv head), so the pool is `kv_pages` [n_slots, 2, KV, bt, D]
+and one grid step fetches the K and the V tile of its kv head in ONE
+(1, 2, 1, bt, D) block. The tiled dims are (bt, D) — TPU's (8, 128) rule
+holds for bt % 8 == 0 and D % 128 == 0 (or D the whole head) — and the
+kernel reads the pool in place: no K/V split or relayout of the pool.
 """
 from __future__ import annotations
 
@@ -26,7 +33,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -2.3819763e38
 
 
-def _kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, touched_ref,
+def _kernel(bt_ref, lens_ref, q_ref, kv_ref, o_ref, touched_ref,
             m_scr, l_scr, acc_scr, *, block_tokens: int, n_blocks: int,
             scale: float):
     b = pl.program_id(0)
@@ -40,8 +47,8 @@ def _kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, touched_ref,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32) * scale       # [REP, D]
-    k = k_ref[0, :, 0].astype(jnp.float32)            # [bt, D]
-    v = v_ref[0, :, 0].astype(jnp.float32)            # [bt, D]
+    k = kv_ref[0, 0, 0].astype(jnp.float32)           # [bt, D]
+    v = kv_ref[0, 1, 0].astype(jnp.float32)           # [bt, D]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # [REP, bt]
 
@@ -63,7 +70,7 @@ def _kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, touched_ref,
 
     # fused access-bit recording: this block was dereferenced
     was_used = (j * block_tokens < lens_ref[b]) & (bt_ref[b, j] >= 0)
-    touched_ref[0, 0] = was_used.astype(jnp.int32)
+    touched_ref[b, j] = was_used.astype(jnp.int32)
 
     @pl.when(j == n_blocks - 1)
     def _finish():
@@ -71,17 +78,16 @@ def _kernel(bt_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, touched_ref,
         o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
-def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, block_tables: jax.Array,
-                           seq_lens: jax.Array, *, scale: float = None,
-                           interpret: bool = True):
-    """q: [B, KV, REP, D]; k_pages/v_pages: [n_slots, bt, KV, D];
-    block_tables: [B, MB] int32 physical slot ids (-1 unused);
-    seq_lens: [B] int32.
+def paged_attention_pallas(q: jax.Array, kv_pages: jax.Array,
+                           block_tables: jax.Array, seq_lens: jax.Array,
+                           *, scale: float = None, interpret: bool = True):
+    """q: [B, KV, REP, D]; kv_pages: [n_slots, 2, KV, bt, D] (K at index
+    0, V at 1 of axis 1); block_tables: [B, MB] int32 physical slot ids
+    (-1 unused); seq_lens: [B] int32.
     Returns (out [B, KV, REP, D], touched [B, MB] int32)."""
     b, kv, rep, d = q.shape
-    n_slots, bt, kv2, d2 = k_pages.shape
-    assert (kv, d) == (kv2, d2)
+    n_slots, two, kv2, bt, d2 = kv_pages.shape
+    assert (two, kv, d) == (2, kv2, d2)
     mb = block_tables.shape[1]
     safe_tables = jnp.where(block_tables >= 0, block_tables, 0) \
         .astype(jnp.int32)
@@ -93,16 +99,15 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, rep, d),
                          lambda i, h, j, tbl, lens: (i, h, 0, 0)),
-            pl.BlockSpec((1, bt, 1, d),
-                         lambda i, h, j, tbl, lens: (tbl[i, j], 0, h, 0)),
-            pl.BlockSpec((1, bt, 1, d),
-                         lambda i, h, j, tbl, lens: (tbl[i, j], 0, h, 0)),
+            pl.BlockSpec((1, 2, 1, bt, d),
+                         lambda i, h, j, tbl, lens: (tbl[i, j], 0, h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, rep, d),
                          lambda i, h, j, tbl, lens: (i, h, 0, 0)),
-            pl.BlockSpec((1, 1),
-                         lambda i, h, j, tbl, lens: (i, j)),
+            # the access bits are scalars: the whole [B, MB] map stays in
+            # SMEM for the kernel's lifetime, one store per grid step
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
             pltpu.VMEM((rep, 1), jnp.float32),
@@ -121,5 +126,6 @@ def paged_attention_pallas(q: jax.Array, k_pages: jax.Array,
             jax.ShapeDtypeStruct((b, mb), jnp.int32),
         ],
         interpret=interpret,
-    )(safe_tables, seq_lens.astype(jnp.int32), q, k_pages, v_pages)
+        name="paged_attention",
+    )(safe_tables, seq_lens.astype(jnp.int32), q, kv_pages)
     return out, touched

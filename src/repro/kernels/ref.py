@@ -71,31 +71,29 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 # paged_attention — decode through the object table (block-paged KV)
 # ---------------------------------------------------------------------------
-def paged_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
-                    block_tables: jax.Array, seq_lens: jax.Array,
-                    block_tokens: int) -> Tuple[jax.Array, jax.Array]:
+def paged_attention(q: jax.Array, kv_pages: jax.Array,
+                    block_tables: jax.Array, seq_lens: jax.Array
+                    ) -> Tuple[jax.Array, jax.Array]:
     """q: [B,H,D] one token per sequence.
-    k_pages/v_pages: [n_slots, block_tokens, KV, D] — the HadesPool data.
-    block_tables: [B, max_blocks] physical slot per logical KV block
-    (-1 = unused). seq_lens: [B].
+    kv_pages: [n_slots, 2, KV, block_tokens, D] — the HadesPool data (K at
+    index 0 of axis 1, V at 1). block_tables: [B, max_blocks] physical
+    slot per logical KV block (-1 = unused). seq_lens: [B].
     Returns (out [B,H,D], touched [B, max_blocks] bool — the access bits
     the fused tracking would record)."""
     b, h, d = q.shape
-    n_slots, bt, kv, _ = k_pages.shape
+    n_slots, _, kv, bt, _ = kv_pages.shape
     mb = block_tables.shape[1]
-    n_rep = h // kv
     safe = jnp.maximum(block_tables, 0)
-    k = k_pages[safe]                       # [B, mb, bt, KV, D]
-    v = v_pages[safe]
-    k = k.reshape(b, mb * bt, kv, d)
-    v = v.reshape(b, mb * bt, kv, d)
+    pages = kv_pages[safe]                  # [B, mb, 2, KV, bt, D]
+    k = pages[:, :, 0].transpose(0, 1, 3, 2, 4).reshape(b, mb * bt, kv, d)
+    v = pages[:, :, 1].transpose(0, 1, 3, 2, 4).reshape(b, mb * bt, kv, d)
     pos = jnp.arange(mb * bt)[None]
     valid = (pos < seq_lens[:, None]) & \
         (jnp.repeat(block_tables >= 0, bt, axis=1))
     out, m, l = attn_lib.decode_attention_partial(
         q[:, None], k, v, valid)
     out = out / jnp.moveaxis(jnp.maximum(l, 1e-30), 1, -1)[..., None]
-    n_blocks_used = (seq_lens + block_tokens - 1) // block_tokens
+    n_blocks_used = (seq_lens + bt - 1) // bt
     touched = (jnp.arange(mb)[None] < n_blocks_used[:, None]) & \
         (block_tables >= 0)
     return out[:, 0].astype(q.dtype), touched

@@ -12,6 +12,7 @@ XLA_FLAGS=--xla_force_host_platform_device_count=512 before any import).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # TPU v5e hardware constants (per chip) — shared by roofline + kernels
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
@@ -20,16 +21,24 @@ ICI_BW = 50e9                     # bytes/s per link
 HBM_BYTES = 16 * 1024 ** 3        # 16 GiB
 
 
+def _mesh(shape, axes):
+    """Auto axes: the sharding rules and `with_sharding_constraint` hints
+    are written for the compiler's propagation (jax.make_mesh would
+    otherwise build Explicit axes, which refuse those hints)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model_axis: int = 1):
     """Tiny mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    return _mesh((n // model_axis, model_axis), ("data", "model"))
 
 
 def data_axes(mesh) -> tuple:

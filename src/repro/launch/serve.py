@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core import backend as be
+from repro.launch import compile_cache
 from repro.models.model import Model
 from repro.runtime.server import Request, Server, ServerConfig
 
@@ -56,6 +57,7 @@ def main():
         ap.error(f"--hbm-target-mb is not applicable to {args.backend!r}"
                  " (it declares no pressure field)")
 
+    compile_cache.enable()
     cfg = get_config(args.arch, reduced=args.reduced)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -52,8 +53,15 @@ class KVCacheConfig:
         return self.num_layers * self.batch * self.max_blocks
 
     @property
+    def slot_shape(self) -> Tuple[int, int, int, int]:
+        """One KV block's slot: K/V, kv head, token, head dim. The pool
+        data is then the paged-attention kernel's `kv_pages` as it
+        stands: its tiled dims are (block_tokens, head_dim)."""
+        return (2, self.num_kv_heads, self.block_tokens, self.head_dim)
+
+    @property
     def slot_words(self) -> int:
-        return 2 * self.block_tokens * self.num_kv_heads * self.head_dim
+        return math.prod(self.slot_shape)
 
     def obj_id(self, layer, seq, block):
         return (layer * self.batch + seq) * self.max_blocks + block
@@ -62,7 +70,7 @@ class KVCacheConfig:
         return pl.make_config(
             self.max_objects, self.slot_words, sb_slots=self.sb_slots,
             page_slots=max(self.sb_slots // 4, 1), slack=self.slack,
-            dtype=self.dtype)
+            dtype=self.dtype, slot_shape=self.slot_shape)
 
 
 def init(cfg: KVCacheConfig, backend: Optional[be.Backend] = None,
@@ -144,15 +152,20 @@ def append_layer(cfg: KVCacheConfig, state: Dict, layer, k: jax.Array,
 
     words = pool["table"][jnp.minimum(obj, cfg.max_objects - 1)]
     slots = ot.slot_of(words).astype(jnp.int32)         # [B]
-    data = pool["data"].reshape(
-        -1, 2, cfg.block_tokens, cfg.num_kv_heads, cfg.head_dim)
-    # overflow/inactive lanes route out of bounds and are dropped,
-    # never clamped
-    slots = jnp.where(fits, slots, data.shape[0])
-    kv_tok = jnp.stack([k, v], axis=1)        # [B, 2, KV, D]
-    data = data.at[slots, :, off, :, :].set(kv_tok.astype(data.dtype),
-                                            mode="drop")
-    pool = dict(pool, data=data.reshape(pool["data"].shape))
+    data = pool["data"]                       # [n_slots + 1, 2, KV, bt, D]
+    # overflow/inactive lanes write ZEROS onto the pool's all-zero
+    # scratch row (its invariant holds), never onto a live slot
+    slots = jnp.where(fits, slots, pcfg.n_slots)
+    kv_tok = jnp.where(fits[:, None, None, None],
+                       jnp.stack([k, v], axis=1), 0).astype(data.dtype)
+    # one in-place row write per lane: a batched scatter at a token
+    # offset inside the tiled (bt, D) dims makes XLA:TPU copy the whole
+    # pool into another layout and back around it, in every layer
+    for i in range(cfg.batch):
+        data = jax.lax.dynamic_update_slice(
+            data, kv_tok[i][None, :, :, None, :],
+            (slots[i], 0, 0, off[i], 0))
+    pool = dict(pool, data=data)
     return dict(state, pool=pool, block_tables=bt)
 
 
@@ -221,7 +234,8 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
     CPU runs the pure-jnp oracle — interpret-mode kernel emulation is
     correctness-only and orders of magnitude too slow for the serving
     hot path (tests/test_kernels.py keeps the two bit-compatible on the
-    touched bits and within fp tolerance on the outputs)."""
+    touched bits and within fp tolerance on the outputs). Both read the
+    pool data in place as [n_slots + 1, 2, KV, bt, D] pages."""
     pcfg = cfg.pool_config()
     pool = state["pool"]
     tbl = state["block_tables"][layer]               # [B, MB] logical ids
@@ -234,15 +248,11 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
 
-    pages = pool["data"].reshape(
-        -1, 2, cfg.block_tokens, cfg.num_kv_heads, cfg.head_dim)
     if use_pallas:
-        out, touched = kops.paged_attention(
-            q, pages[:, 0], pages[:, 1], slots, lens)
+        out, touched = kops.paged_attention(q, pool["data"], slots, lens)
     else:
         from repro.kernels import ref as kref
-        out, touched = kref.paged_attention(
-            q, pages[:, 0], pages[:, 1], slots, lens, cfg.block_tokens)
+        out, touched = kref.paged_attention(q, pool["data"], slots, lens)
 
     # inactive lanes really do return ZEROS: with lens == 0 the kernels'
     # all-masked softmax degenerates to a mean over slot 0's payload (a

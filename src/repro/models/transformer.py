@@ -226,7 +226,13 @@ def attn_block_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,
 # ---------------------------------------------------------------------------
 # Family: decoder-only LM (dense, MoE, VLM backbone)
 # ---------------------------------------------------------------------------
+@functools.partial(jax.jit, static_argnums=0)
 def init_lm(cfg: ModelConfig, key) -> dict:
+    """Random parameters from `key`. Jitted: each weight's f32 draw, its
+    scale and its cast to cfg.dtype fuse into one program, so no f32 copy
+    of a whole stacked weight is ever materialised (an eager chatglm3-6b
+    init would hold a 6.3 GB f32 draw and its scaled copy beside the
+    bf16 weights)."""
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8)
     n_attn = sum(1 for k in cfg.blocks if k == ATTN)

@@ -509,14 +509,9 @@ class Server:
                 toks[i] = row
 
             # -- ONE dispatch: events + W steps + collect ----------------
-            events = {
-                "free": jnp.zeros((w, b), jnp.bool_).at[0].set(free),
-                "admit": jnp.zeros((w, b), jnp.bool_).at[0].set(admit),
-                "temp": jnp.zeros((w, b), jnp.float32).at[0].set(temp),
-                "topk": jnp.zeros((w, b), jnp.int32).at[0].set(topk),
-            }
             carry, outs, rep = self._win_serve(
-                params, self._carry(), jnp.asarray(toks.T), events,
+                params, self._carry(), jnp.asarray(toks.T),
+                self._window_events(w, free, admit, temp, topk),
                 do_sample=do_sample)
             self._uncarry(carry)
             self._steps += w
@@ -567,6 +562,32 @@ class Server:
                           active=jnp.ones((b,), jnp.bool_))
         self._sample_in_scan = False
         return results
+
+    @staticmethod
+    def _window_events(w: int, free, admit, temp, topk) -> Dict:
+        """The serving window's lane events, [W, B] each: the host's
+        window-entry decisions on step 0, nothing on later steps."""
+        b = len(free)
+        return {
+            "free": jnp.zeros((w, b), jnp.bool_).at[0].set(free),
+            "admit": jnp.zeros((w, b), jnp.bool_).at[0].set(admit),
+            "temp": jnp.zeros((w, b), jnp.float32).at[0].set(temp),
+            "topk": jnp.zeros((w, b), jnp.int32).at[0].set(topk),
+        }
+
+    def lower_serve_window(self, params, *, do_sample: bool = False):
+        """`serve`'s window program (lane events + W steps + collect, the
+        one dispatch per window) lowered at this server's geometry, for
+        compile checks: its HLO text (`.as_text()`), and `.compile()`'s
+        memory analysis. Nothing runs and the carry is not consumed."""
+        w = self.cfg.window or self.cfg.collect_every
+        b = self.cfg.batch
+        no = np.zeros((b,), bool)
+        events = self._window_events(w, no, no, np.zeros((b,), np.float32),
+                                     np.zeros((b,), np.int32))
+        return self._win_serve.lower(params, self._carry(),
+                                     jnp.zeros((w, b), jnp.int32), events,
+                                     do_sample=do_sample)
 
     def reset(self, active: bool = True) -> None:
         """Fresh serving state (empty pool, zeroed clock/reports/sampling

@@ -101,8 +101,7 @@ def test_flash_attention_sweep(b, s, h, kv, d, causal, window, dtype):
 def test_paged_attention_sweep(b, h, kv, d, bt, mb):
     n_slots = 32
     q = _arr((b, h, d))
-    kp = _arr((n_slots, bt, kv, d))
-    vp = _arr((n_slots, bt, kv, d))
+    kv_pages = _arr((n_slots, 2, kv, bt, d))
     lens = jnp.asarray(RNG.integers(1, bt * mb, b), jnp.int32)
     tables = []
     for i in range(b):
@@ -111,8 +110,8 @@ def test_paged_attention_sweep(b, h, kv, d, bt, mb):
             [-1] * (mb - used)
         tables.append(row)
     tables = jnp.asarray(tables, jnp.int32)
-    got_o, got_t = ops.paged_attention(q, kp, vp, tables, lens)
-    want_o, want_t = ref.paged_attention(q, kp, vp, tables, lens, bt)
+    got_o, got_t = ops.paged_attention(q, kv_pages, tables, lens)
+    want_o, want_t = ref.paged_attention(q, kv_pages, tables, lens)
     assert np.abs(np.asarray(got_o) - np.asarray(want_o)).max() < 2e-5
     assert np.array_equal(np.asarray(got_t), np.asarray(want_t))
 

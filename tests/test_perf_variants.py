@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_host_mesh
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
 
@@ -44,7 +45,7 @@ def test_moe_sharding_hints_do_not_change_math(rng):
     x = jnp.asarray(rng.normal(size=(2, 8, cfg.d_model))
                     .astype(np.float32))
     base, _, _ = jax.jit(lambda: moe_lib.moe_block(p, x, cfg))()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_host_mesh()          # one CPU device: a (1, 1) mesh
     from jax.sharding import PartitionSpec as P
     moe_lib.set_sharding_hints({"dispatch": P(None, "data", None),
                                 "hidden": P(None, "data", "model")})

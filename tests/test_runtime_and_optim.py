@@ -55,15 +55,14 @@ def test_compression_roundtrip_and_error_feedback():
 def test_compressed_allreduce_under_shard_map():
     n = len(jax.devices())
     mesh = jax.make_mesh((n,), ("d",))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     g = {"w": jnp.ones((n, 64), jnp.float32)}
 
     def f(gs):
         red, err = compression.compressed_allreduce(gs, "d")
         return red, err
-    out, err = shard_map(f, mesh=mesh, in_specs=(P("d"),),
-                         out_specs=P("d"))(g)
+    out, err = jax.shard_map(f, mesh=mesh, in_specs=(P("d"),),
+                             out_specs=P("d"))(g)
     # sum over n shards of ones = n (per row)
     assert np.allclose(np.asarray(out["w"]), n, atol=0.1)
 

@@ -11,17 +11,8 @@ from repro.configs.shapes import SHAPES
 from repro.launch import shardings as sh
 from repro.models.model import Model
 
-def _amesh(axis_sizes, axis_names):
-    """AbstractMesh across jax versions: new API takes (sizes, names),
-    jax<=0.4.x takes a ((name, size), ...) shape tuple."""
-    try:
-        return AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-
-
-MESH = _amesh((16, 16), ("data", "model"))
-MESH3 = _amesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _check_divisible(shape, spec, axis_sizes):

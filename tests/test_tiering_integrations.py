@@ -25,11 +25,14 @@ def _fill(state, steps, rng):
     return state, ks, vs
 
 
-def test_paged_attend_matches_dense(rng):
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_paged_attend_matches_dense(rng, use_pallas):
+    """Both attend paths read the pool in place ([n+1, 2, KV, bt, D]
+    slots) and match dense attention over the appended k/v."""
     state, ks, vs = _fill(kvc.init(CFG), 11, rng)
     q = jnp.asarray(rng.normal(size=(3, 4, 16)).astype(np.float32))
     for layer in (0, 1):
-        out, state = kvc.attend(CFG, state, layer, q)
+        out, state = kvc.attend(CFG, state, layer, q, use_pallas=use_pallas)
         K = jnp.stack([k[layer] for k in ks], axis=1)
         V = jnp.stack([v[layer] for v in vs], axis=1)
         want = attn.decode_attention(q[:, None], K, V,
