@@ -180,6 +180,7 @@ def _plan_moves(cfg: pl.PoolConfig, state: Dict, ids_m: jax.Array,
     return state, src, dst, ok
 
 
+@jax.named_scope("migrate")
 def migrate(cfg: pl.PoolConfig, state: Dict, to_hot: jax.Array,
             to_cold: jax.Array, *, use_pallas: bool = False,
             move_budget: int = 256) -> Tuple[Dict, jax.Array, jax.Array]:
@@ -237,9 +238,11 @@ def migrate(cfg: pl.PoolConfig, state: Dict, to_hot: jax.Array,
     return state, jnp.sum(ok_h), jnp.sum(ok_c)
 
 
+@jax.named_scope("collect")
 def collect(pool_cfg: pl.PoolConfig, col_cfg: CollectorConfig,
             state: Dict) -> Tuple[Dict, Dict[str, jax.Array]]:
-    """One Object Collector pass. Returns (state, report)."""
+    """One Object Collector pass, under the named scope `collect` (its
+    migration under `migrate`). Returns (state, report)."""
     # one table sweep: CIW update + migration masks + ATC-veto diagnostic
     # (the access_scan kernel emits all four on the use_pallas path)
     new_tbl, to_hot, to_cold, skipped_atc = classify(pool_cfg, col_cfg,

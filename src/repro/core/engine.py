@@ -102,9 +102,10 @@ def collect_and_backend(pool_cfg: pl.PoolConfig, col_cfg: col.CollectorConfig,
     stats = report.pop("sb_stats")
     signals = {"proactive_ok": report["proactive_ok"],
                "epoch": state["epoch"]}
-    bstate, tier, evict, telemetry = backend.step(
-        pool_cfg, state["bstate"], stats, state["sb_tier"],
-        state["sb_evict"], signals)
+    with jax.named_scope("backend"):
+        bstate, tier, evict, telemetry = backend.step(
+            pool_cfg, state["bstate"], stats, state["sb_tier"],
+            state["sb_evict"], signals)
     state = dict(state, bstate=bstate, sb_tier=tier, sb_evict=evict)
     report.update(telemetry)
     occupied = stats["occupancy"] > 0

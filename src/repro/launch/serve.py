@@ -5,12 +5,16 @@ cache (runtime/server.py), reporting KV RSS + collector activity.
 `--mode generate` (default) teacher-forces one fixed batch through
 `Server.generate`; `--mode serve` drives the continuous-batching queue
 (`Server.serve`): more requests than lanes, lane churn at one dispatch
-per window, per-window RSS-vs-live gauges. `--temperature/--top-k`
+per window, per-window RSS-vs-live gauges, the p50/p95 of each request's
+queue wait, time to first token and completion time (from the `serve`
+call), and the median host milliseconds of each window phase
+(docs/serving.md). `--temperature/--top-k`
 switch on in-scan sampling (a PRNG key is derived from --seed).
 """
 from __future__ import annotations
 
 import argparse
+import time
 
 import jax
 import jax.numpy as jnp
@@ -88,6 +92,7 @@ def main():
                         temperature=args.temperature, top_k=args.top_k)
                 for _ in range(args.requests)]
         key = sample_key if args.temperature > 0 else None
+        t0 = time.perf_counter()
         results = srv.serve(params, reqs, key=key)
         n_windows = len(srv.serve_log)
         print(f"served {len(results)} requests on {lanes} lanes in "
@@ -97,6 +102,16 @@ def main():
         print(f"KV RSS peak {peak/2**20:.2f} MiB -> final "
               f"{srv.kv_rss_bytes()/2**20:.2f} MiB "
               f"(reclaimed after finishes)")
+        for what, stamp in (("queue wait", "t_admitted"),
+                            ("first token", "t_first_token"),
+                            ("completion", "t_finished")):
+            s = [getattr(r, stamp) - t0 for r in results]
+            print(f"{what}: p50 {np.percentile(s, 50):.3f} s, "
+                  f"p95 {np.percentile(s, 95):.3f} s")
+        phases = srv.serve_log[0]["host_ms"]
+        print("host ms per window (median): " + ", ".join(
+            f"{p} {np.median([e['host_ms'][p] for e in srv.serve_log]):.3f}"
+            for p in phases))
     for r in srv.reports[-3:]:
         print("  collector:", {k: round(v, 4) for k, v in r.items()})
 

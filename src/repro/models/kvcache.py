@@ -119,6 +119,7 @@ def append(cfg: KVCacheConfig, state: Dict, k: jax.Array, v: jax.Array
     return advance_pos(state)
 
 
+@jax.named_scope("kv_append")
 def append_layer(cfg: KVCacheConfig, state: Dict, layer, k: jax.Array,
                  v: jax.Array) -> Dict:
     """k/v: [B, KV, D] — ONE layer's k/v for the current token, for the

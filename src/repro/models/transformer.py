@@ -164,13 +164,18 @@ def decode_layer_step(p: dict, x: jax.Array, cfg: ModelConfig, positions,
     x: [B,1,D]; positions: [B,1] (per-sequence positions, pre-broadcast);
     attend_fn(q, k, v) -> (attn out reshapeable to [B,1,H*Dh], aux) with
     q [B,1,H,Dh], k/v [B,1,KV,Dh]; `aux` is whatever cache/pool state the
-    caller must thread onward. Returns (x', aux, expert_counts)."""
+    caller must thread onward. Returns (x', aux, expert_counts).
+
+    Named scopes `qkv`, `attention` (attend_fn and the output projection)
+    and `ffn` label the layer's ops in the compiled HLO's op_name."""
     b = x.shape[0]
     hd = cfg.resolved_head_dim
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _qkv(p, h, cfg, positions)
-    o, aux = attend_fn(q, k, v)
-    x = x + jnp.einsum("bse,ed->bsd", o.reshape(b, 1, -1), p["wo"])
+    with jax.named_scope("qkv"):
+        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+        q, k, v = _qkv(p, h, cfg, positions)
+    with jax.named_scope("attention"):
+        o, aux = attend_fn(q, k, v)
+        x = x + jnp.einsum("bse,ed->bsd", o.reshape(b, 1, -1), p["wo"])
 
     if enc_kv is not None:
         hx = L.rms_norm(x, p["ln_x"], cfg.norm_eps)
@@ -179,20 +184,21 @@ def decode_layer_step(p: dict, x: jax.Array, cfg: ModelConfig, positions,
         ox = attn_lib.cross_attention(qx, enc_kv[0], enc_kv[1])
         x = x + jnp.einsum("bse,ed->bsd", ox.reshape(b, 1, -1), p["xo"])
 
-    h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-    counts = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
-    if cfg.num_experts:
-        t = h2.shape[0] * h2.shape[1]
-        if cfg.hades.expert_gather_decode and \
-                t * cfg.experts_per_token < cfg.num_experts:
-            # HADES hot-expert principle on the weight stream: fetch only
-            # the routed experts (exact; wins when T*k < E)
-            f, _, counts = moe_lib.moe_block_gathered(p["moe"], h2, cfg)
+    with jax.named_scope("ffn"):
+        h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        counts = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
+        if cfg.num_experts:
+            t = h2.shape[0] * h2.shape[1]
+            if cfg.hades.expert_gather_decode and \
+                    t * cfg.experts_per_token < cfg.num_experts:
+                # HADES hot-expert principle on the weight stream: fetch
+                # only the routed experts (exact; wins when T*k < E)
+                f, _, counts = moe_lib.moe_block_gathered(p["moe"], h2, cfg)
+            else:
+                f, _, counts = moe_lib.moe_block(p["moe"], h2, cfg)
         else:
-            f, _, counts = moe_lib.moe_block(p["moe"], h2, cfg)
-    else:
-        f = L.mlp(p["ffn"], h2, cfg.mlp_gated)
-    return x + f, aux, counts
+            f = L.mlp(p["ffn"], h2, cfg.mlp_gated)
+        return x + f, aux, counts
 
 
 def attn_block_decode(p: dict, x: jax.Array, cfg: ModelConfig, cache: dict,

@@ -30,12 +30,23 @@ per window while freed cold blocks become the fragmentation the
 collector tidies for the backend to reclaim. Sampling (temperature /
 top-k, per lane) runs INSIDE the scan under a carried PRNG key
 (runtime/sampling.py).
+
+MEASUREMENT (docs/serving.md): `serve` records itself on
+`time.perf_counter`'s clock — each window's host phases as profiler spans
+(`serve.window`, `serve.<phase>`) and in `serve_log`, each request's
+admission, first token and finish on its `Completion` — and the window
+program returns the post-window KV gauges, so `serve` runs no device work
+of its own for them. The window program's ops carry `jax.named_scope`s
+(qkv, kv_append, attention, ffn, logits, sample, lane_events, collect,
+migrate, backend) that reach the compiled HLO's `op_name` metadata.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -98,11 +109,20 @@ class Completion:
     """`Server.serve`'s per-request result. `tokens` are the generated
     tokens (EOS included when it fired); `finish_reason` is "eos" or
     "length" (max_new or lane capacity); `windows` is the [admitted,
-    finished] window-index span the request occupied a lane for."""
+    finished] window-index span the request occupied a lane for.
+
+    The stamps are `time.perf_counter()` seconds, the clock of
+    `serve_log`: `t_admitted` is the admitting window's `t_dispatch`,
+    `t_first_token` the `t_tokens` of the window whose sync delivered the
+    first generated token, `t_finished` that of the window that delivered
+    the last kept one (window `windows[1] - 1`)."""
     rid: int
     tokens: List[int]
     finish_reason: str
     windows: Tuple[int, int]
+    t_admitted: float
+    t_first_token: float
+    t_finished: float
 
 
 @dataclasses.dataclass
@@ -115,6 +135,25 @@ class _Lane:
     out: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
     reason: str = ""
+    t_admitted: float = 0.0
+    t_first_token: float = 0.0
+    t_finished: float = 0.0
+
+
+@contextlib.contextmanager
+def _phase(host_ms: Dict[str, float], name: str):
+    """One host phase of a serving window: a profiler span
+    `serve.<name>` and its milliseconds in `host_ms[name]`. Yields the
+    phase's start on `time.perf_counter`."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(f"serve.{name}"):
+        yield t0
+    host_ms[name] = 1e3 * (time.perf_counter() - t0)
+
+
+def _live_blocks(kvstate: Dict) -> jax.Array:
+    """Allocated KV blocks: the block-table entries that hold an id."""
+    return jnp.sum(kvstate["block_tables"] >= 0)
 
 
 class Server:
@@ -170,9 +209,11 @@ class Server:
             layer_body, (x, state),
             (jnp.arange(mc.num_layers), params["layers"]))
         state = kvc.advance_pos(state)
-        h = L.rms_norm(h, params["final_ln"], mc.norm_eps)
-        out_t = params["embed"].T if mc.tie_embeddings else params["out"]
-        logits = L.logits_head(out_t, h)[:, 0]
+        with jax.named_scope("logits"):
+            h = L.rms_norm(h, params["final_ln"], mc.norm_eps)
+            out_t = params["embed"].T if mc.tie_embeddings else \
+                params["out"]
+            logits = L.logits_head(out_t, h)[:, 0]
         return state, logits
 
     def _build_programs(self):
@@ -195,14 +236,15 @@ class Server:
             tok = jnp.where(forced >= 0, forced, carry["tok"])
             tok = jnp.where(carry["kv"]["active"], tok, 0)
             kvstate, logits = self._model_step(params, carry["kv"], tok)
-            if do_sample:
-                key, sub = jax.random.split(carry["key"])
-                nxt = sampling.sample(logits, sub, carry["temp"],
-                                      carry["topk"])
-                carry = dict(carry, kv=kvstate, tok=nxt, key=key)
-            else:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                carry = dict(carry, kv=kvstate, tok=nxt)
+            with jax.named_scope("sample"):
+                if do_sample:
+                    key, sub = jax.random.split(carry["key"])
+                    nxt = sampling.sample(logits, sub, carry["temp"],
+                                          carry["topk"])
+                    carry = dict(carry, kv=kvstate, tok=nxt, key=key)
+                else:
+                    nxt = jnp.argmax(logits, -1).astype(jnp.int32)
+                    carry = dict(carry, kv=kvstate, tok=nxt)
             return carry, {"logits": logits, "tok": nxt}
 
         def win_collect(carry):
@@ -212,6 +254,7 @@ class Server:
         def win_arm(carry):
             return dict(carry, kv=kvc.arm(carry["kv"]))
 
+        @jax.named_scope("lane_events")
         def win_events(carry, ev):
             """Window-entry lane events, fused into the window dispatch:
             finished lanes free ALL their KV through the pool op stream,
@@ -240,9 +283,18 @@ class Server:
 
         def serve_aligned(params, carry, toks, events, do_sample):
             """The continuous-batching window: lane events applied at
-            the window entry, then W steps + collect — one dispatch."""
-            return _programs(params, do_sample,
-                             pre_fn=win_events)[1](carry, toks, events)
+            the window entry, then W steps + collect — one dispatch.
+            Returns (carry, outs, reports, gauges): `gauges` are the
+            post-window KV gauges, computed as `kv_rss_bytes` and
+            `kv_live_bytes` compute them (`rss_bytes`, `live_blocks`)."""
+            carry, outs, reports = _programs(
+                params, do_sample, pre_fn=win_events)[1](carry, toks,
+                                                         events)
+            kv = carry["kv"]
+            gauges = {"rss_bytes": pl.rss_bytes(self.kv_cfg.pool_config(),
+                                                kv["pool"]),
+                      "live_blocks": _live_blocks(kv)}
+            return carry, outs, reports, gauges
 
         def step_apply(params, carry, tok, do_arm, do_collect,
                        do_sample):
@@ -429,8 +481,18 @@ class Server:
         final lanes drain through one last all-inactive window so every
         request's KV leaves the pool through the same op stream. Starts
         from a fresh pool (`reset(active=False)`) and ends back in the
-        fixed-batch contract (drained pool, all lanes active at pos 0);
-        per-window RSS/live-bytes/churn gauges land in `self.serve_log`.
+        fixed-batch contract (drained pool, all lanes active at pos 0).
+
+        `self.serve_log` gets one entry per window: churn counts, the
+        window program's post-window `rss_bytes`/`live_bytes`,
+        `lane_steps` (lanes x W) and `useful_lane_steps` (those that fed
+        a prompt token or produced a kept token), and on
+        `time.perf_counter`'s clock `t_dispatch` (just before the
+        dispatch), `t_ready` (the host first holds the window's outputs),
+        `t_tokens` (the sampled tokens are on the host) and `host_ms`,
+        the milliseconds of each host phase (schedule, inputs, dispatch,
+        reports, tokens, lanes, log), each also a profiler span
+        `serve.<phase>` inside the step span `serve.window`.
 
         Returns one `Completion` per request, in submission order."""
         w = self.cfg.window or self.cfg.collect_every
@@ -467,91 +529,134 @@ class Server:
             # generous safety valve: sequential worst case + drain
             max_windows = 2 + sum(
                 -(-(len(r.prompt) + r.max_new) // w) + 1 for r in requests)
+        overlap = self.cfg.overlap_collect
+        slot_bytes = self.kv_cfg.pool_config().slot_bytes
         window_idx = 0
         pending = None
         while True:
-            # -- resolve lane events (host side, window boundary) --------
-            free = np.zeros((b,), bool)
-            admit = np.zeros((b,), bool)
-            temp = np.zeros((b,), np.float32)
-            topk = np.zeros((b,), np.int32)
-            for i in range(b):
-                ln = lanes[i]
-                if ln is not None and ln.done:
-                    free[i] = True
-                    results[ln.rid] = Completion(
-                        ln.rid, ln.out, ln.reason,
-                        (ln.admitted_at, window_idx))
-                    lanes[i] = None
-                if lanes[i] is None and queue:
-                    rid, req = queue.popleft()
-                    lanes[i] = _Lane(rid=rid, req=req,
-                                     admitted_at=window_idx)
-                    admit[i] = True
-                    temp[i] = req.temperature
-                    topk[i] = req.top_k
-            if not any(lanes) and not free.any():
-                break                     # queue drained, pool empty
-            if window_idx >= max_windows:
-                raise RuntimeError(
-                    f"serve exceeded max_windows={max_windows} "
-                    "(lane scheduling stuck?)")
+            host_ms: Dict[str, float] = {}
+            with jax.profiler.StepTraceAnnotation("serve.window",
+                                                  step_num=window_idx):
+                # -- resolve lane events (host side, window boundary) ----
+                with _phase(host_ms, "schedule"):
+                    free = np.zeros((b,), bool)
+                    admit = np.zeros((b,), bool)
+                    temp = np.zeros((b,), np.float32)
+                    topk = np.zeros((b,), np.int32)
+                    for i in range(b):
+                        ln = lanes[i]
+                        if ln is not None and ln.done:
+                            free[i] = True
+                            results[ln.rid] = Completion(
+                                ln.rid, ln.out, ln.reason,
+                                (ln.admitted_at, window_idx),
+                                ln.t_admitted, ln.t_first_token,
+                                ln.t_finished)
+                            lanes[i] = None
+                        if lanes[i] is None and queue:
+                            rid, req = queue.popleft()
+                            lanes[i] = _Lane(rid=rid, req=req,
+                                             admitted_at=window_idx)
+                            admit[i] = True
+                            temp[i] = req.temperature
+                            topk[i] = req.top_k
+                if not any(lanes) and not free.any():
+                    break                 # queue drained, pool empty
+                if window_idx >= max_windows:
+                    raise RuntimeError(
+                        f"serve exceeded max_windows={max_windows} "
+                        "(lane scheduling stuck?)")
 
-            # -- the window's forced tokens ------------------------------
-            toks = np.zeros((b, w), np.int32)
-            for i, ln in enumerate(lanes):
-                if ln is None:
-                    continue
-                row = np.full((w,), -1, np.int32)
-                prompt = ln.req.prompt
-                n_force = min(max(len(prompt) - ln.steps, 0), w)
-                row[:n_force] = prompt[ln.steps:ln.steps + n_force]
-                toks[i] = row
+                # -- the window's forced tokens and lane events ----------
+                with _phase(host_ms, "inputs"):
+                    toks = np.zeros((b, w), np.int32)
+                    for i, ln in enumerate(lanes):
+                        if ln is None:
+                            continue
+                        row = np.full((w,), -1, np.int32)
+                        prompt = ln.req.prompt
+                        n_force = min(max(len(prompt) - ln.steps, 0), w)
+                        row[:n_force] = prompt[ln.steps:ln.steps + n_force]
+                        toks[i] = row
+                    toks = jnp.asarray(toks.T)
+                    events = self._window_events(w, free, admit, temp, topk)
 
-            # -- ONE dispatch: events + W steps + collect ----------------
-            carry, outs, rep = self._win_serve(
-                params, self._carry(), jnp.asarray(toks.T),
-                self._window_events(w, free, admit, temp, topk),
-                do_sample=do_sample)
-            self._uncarry(carry)
-            self._steps += w
-            self.dispatches += 1
-            window_idx += 1
-            if self.cfg.overlap_collect:
-                if pending is not None:
-                    self.reports.extend(eng.window_reports(pending))
-                pending = rep
-            else:
-                self.reports.extend(eng.window_reports(rep))
+                # -- ONE dispatch: events + W steps + collect ------------
+                with _phase(host_ms, "dispatch") as t_dispatch:
+                    carry, outs, rep, gauges = self._win_serve(
+                        params, self._carry(), toks, events,
+                        do_sample=do_sample)
+                    self._uncarry(carry)
+                self._steps += w
+                self.dispatches += 1
+                window_idx += 1
+                for i in np.flatnonzero(admit):
+                    lanes[i].t_admitted = t_dispatch
 
-            # -- window-boundary sync: schedule lanes off the samples ----
-            sampled = np.asarray(outs["tok"]).T          # [B, w]
-            for i, ln in enumerate(lanes):
-                if ln is None:
-                    continue
-                p = len(ln.req.prompt)
-                for t in range(w):
-                    if ln.done:
-                        break
-                    s = ln.steps + t
-                    if s < p - 1:
-                        continue                          # prompt phase
-                    ln.out.append(int(sampled[i, t]))
-                    if ln.out[-1] == self.cfg.eos_token:
-                        ln.done, ln.reason = True, "eos"
-                    elif len(ln.out) >= ln.req.max_new:
-                        ln.done, ln.reason = True, "length"
-                    elif s + 1 >= self.cfg.max_len:
-                        ln.done, ln.reason = True, "length"
-                ln.steps += w
-            self.serve_log.append({
-                "window": window_idx,
-                "active": sum(ln is not None for ln in lanes),
-                "admitted": int(admit.sum()), "freed": int(free.sum()),
-                "queued": len(queue),
-                "rss_bytes": self.kv_rss_bytes(),
-                "live_bytes": self.kv_live_bytes(),
-            })
+                # -- window-boundary syncs: reports, then tokens ---------
+                # (t_ready: the host first holds this window's outputs —
+                # at the report sync, or with overlap_collect, whose
+                # report sync reads the previous window, at the tokens)
+                with _phase(host_ms, "reports"):
+                    if overlap:
+                        if pending is not None:
+                            self.reports.extend(
+                                eng.window_reports(pending))
+                        pending = rep
+                    else:
+                        jax.block_until_ready(rep)
+                        t_ready = time.perf_counter()
+                        self.reports.extend(eng.window_reports(rep))
+                with _phase(host_ms, "tokens"):
+                    tok, gauges = jax.device_get((outs["tok"], gauges))
+                    t_tokens = time.perf_counter()
+                if overlap:
+                    t_ready = t_tokens
+
+                # -- schedule lanes off the samples ----------------------
+                useful = 0      # lane-steps that fed a prompt or kept token
+                with _phase(host_ms, "lanes"):
+                    sampled = tok.T                       # [B, w]
+                    for i, ln in enumerate(lanes):
+                        if ln is None:
+                            continue
+                        p = len(ln.req.prompt)
+                        for t in range(w):
+                            if ln.done:
+                                break
+                            useful += 1
+                            s = ln.steps + t
+                            if s < p - 1:
+                                continue                  # prompt phase
+                            ln.out.append(int(sampled[i, t]))
+                            if len(ln.out) == 1:
+                                ln.t_first_token = t_tokens
+                            if ln.out[-1] == self.cfg.eos_token:
+                                ln.done, ln.reason = True, "eos"
+                            elif len(ln.out) >= ln.req.max_new:
+                                ln.done, ln.reason = True, "length"
+                            elif s + 1 >= self.cfg.max_len:
+                                ln.done, ln.reason = True, "length"
+                        if ln.done:
+                            ln.t_finished = t_tokens
+                        ln.steps += w
+                with _phase(host_ms, "log"):
+                    self.serve_log.append({
+                        "window": window_idx,
+                        "active": sum(ln is not None for ln in lanes),
+                        "admitted": int(admit.sum()),
+                        "freed": int(free.sum()),
+                        "queued": len(queue),
+                        "rss_bytes": float(gauges["rss_bytes"]),
+                        "live_bytes": float(int(gauges["live_blocks"])
+                                            * slot_bytes),
+                        "lane_steps": b * w,
+                        "useful_lane_steps": useful,
+                        "t_dispatch": t_dispatch,
+                        "t_ready": t_ready,
+                        "t_tokens": t_tokens,
+                        "host_ms": host_ms,   # "log" lands on exit
+                    })
         if pending is not None:
             self.reports.extend(eng.window_reports(pending))
         assert all(r is not None for r in results)
@@ -617,5 +722,5 @@ class Server:
         """Bytes of LIVE KV objects (allocated blocks x slot bytes) —
         the floor `kv_rss_bytes` reaches at zero fragmentation; the gap
         between the two is what the collector + backend reclaim."""
-        n = int(jnp.sum(self.state["block_tables"] >= 0))
+        n = int(_live_blocks(self.state))
         return float(n * self.kv_cfg.pool_config().slot_bytes)
