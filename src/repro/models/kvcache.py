@@ -255,9 +255,10 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
         from repro.kernels import ref as kref
         out, touched = kref.paged_attention(q, pool["data"], slots, lens)
 
-    # inactive lanes really do return ZEROS: with lens == 0 the kernels'
-    # all-masked softmax degenerates to a mean over slot 0's payload (a
-    # live neighbor's KV) — mask it out rather than leak it
+    # inactive lanes really do return ZEROS: with lens == 0 the Pallas
+    # kernel writes zeros, but the oracle's all-masked softmax degenerates
+    # to a mean over slot 0's payload (a live neighbor's KV) — mask it out
+    # rather than leak it
     out = jnp.where(state["active"][:, None, None], out, 0)
     # the kernel's fused access bits -> object-table access bits
     touched_ids = jnp.where(touched & live & state["active"][:, None],

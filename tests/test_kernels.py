@@ -95,14 +95,25 @@ def test_flash_attention_sweep(b, s, h, kv, d, causal, window, dtype):
 # ---------------------------------------------------------------------------
 # paged_attention
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("b,h,kv,d,bt,mb", [(2, 8, 2, 16, 4, 6),
-                                            (3, 4, 4, 32, 8, 4),
-                                            (1, 8, 1, 64, 16, 3)])
-def test_paged_attention_sweep(b, h, kv, d, bt, mb):
-    n_slots = 32
+@pytest.mark.parametrize("b,h,kv,d,bt,mb,lens", [
+    pytest.param(2, 8, 2, 16, 4, 6, None, id="2-8-2-16-4-6"),
+    pytest.param(3, 4, 4, 32, 8, 4, None, id="3-4-4-32-8-4"),
+    pytest.param(1, 8, 1, 64, 16, 3, None, id="1-8-1-64-16-3"),
+    pytest.param(4, 32, 2, 128, 16, 64, None, id="serving-shape"),
+    pytest.param(4, 8, 2, 16, 4, 6, (0, 9, 0, 24), id="empty-lanes"),
+    pytest.param(3, 8, 2, 32, 8, 8, (8, 16, 40), id="block-boundary"),
+    pytest.param(3, 4, 2, 16, 4, 6, (24, 24, 1), id="full-lanes"),
+])
+def test_paged_attention_sweep(b, h, kv, d, bt, mb, lens):
+    """Every slot that no live block maps to holds NaN for the kernel (the
+    oracle reads the finite pool): a finite output shows the kernel read
+    only live blocks."""
+    n_slots = max(32, b * mb)
     q = _arr((b, h, d))
     kv_pages = _arr((n_slots, 2, kv, bt, d))
-    lens = jnp.asarray(RNG.integers(1, bt * mb, b), jnp.int32)
+    if lens is None:
+        lens = RNG.integers(1, bt * mb, b)
+    lens = jnp.asarray(lens, jnp.int32)
     tables = []
     for i in range(b):
         used = int(np.ceil(int(lens[i]) / bt))
@@ -110,9 +121,17 @@ def test_paged_attention_sweep(b, h, kv, d, bt, mb):
             [-1] * (mb - used)
         tables.append(row)
     tables = jnp.asarray(tables, jnp.int32)
-    got_o, got_t = ops.paged_attention(q, kv_pages, tables, lens)
+    dead = np.ones(n_slots, bool)
+    dead[np.asarray(tables)[np.asarray(tables) >= 0]] = False
+    poisoned = jnp.where(jnp.asarray(dead)[:, None, None, None, None],
+                         jnp.nan, kv_pages)
+    got_o, got_t = ops.paged_attention(q, poisoned, tables, lens)
     want_o, want_t = ref.paged_attention(q, kv_pages, tables, lens)
-    assert np.abs(np.asarray(got_o) - np.asarray(want_o)).max() < 2e-5
+    got_o, want_o = np.asarray(got_o), np.asarray(want_o)
+    live = np.asarray(lens) > 0
+    assert np.isfinite(got_o).all()
+    assert np.abs(got_o[live] - want_o[live]).max() < 2e-5
+    assert (got_o[~live] == 0).all()
     assert np.array_equal(np.asarray(got_t), np.asarray(want_t))
 
 

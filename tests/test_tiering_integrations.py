@@ -40,18 +40,20 @@ def test_paged_attend_matches_dense(rng, use_pallas):
         assert np.abs(np.asarray(out) - np.asarray(want)).max() < 2e-5
 
 
-def test_migration_transparent_to_serving(rng):
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_migration_transparent_to_serving(rng, use_pallas):
     """Collector passes between decode steps must not change attention
-    results (the paper's pointer-update guarantee)."""
+    results (the paper's pointer-update guarantee), read through the
+    oracle or through the kernel, on the slots the collector moved."""
     state, ks, vs = _fill(kvc.init(CFG), 9, rng)
     q = jnp.asarray(rng.normal(size=(3, 4, 16)).astype(np.float32))
-    out0, state = kvc.attend(CFG, state, 1, q)
+    out0, state = kvc.attend(CFG, state, 1, q, use_pallas=use_pallas)
     # several collector passes (some armed) migrate blocks around
     for i in range(5):
         if i % 2:
             state = kvc.arm(state)
         state, rep = kvc.collect(CFG, state)
-    out1, state = kvc.attend(CFG, state, 1, q)
+    out1, state = kvc.attend(CFG, state, 1, q, use_pallas=use_pallas)
     assert np.abs(np.asarray(out0) - np.asarray(out1)).max() < 1e-5
     assert int(state["pool"]["total_moves"]) > 0, "nothing migrated"
 
