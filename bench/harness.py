@@ -3,9 +3,22 @@ jobs through the program's `Server.serve`, the output check, and the
 metrics. `run.py` is the command line around `execute`.
 
 Everything that belongs to one configuration, traffic mix or per-layer
-metric is found by name: `configs/<config>.json` (with its family's
-`models/<family>.py` and `models/<family>_reference.py`),
-`traffic/<mix>.json`, `metrics/<metric>.py`, `cells/<workload>.json`.
+metric is found by name: `configs/<config>.json`, `traffic/<mix>.json`,
+`metrics/<metric>.py`, `cells/<workload>.json`. A configuration file's
+`family` names three modules under `models/`:
+
+- `<family>.py`: the program's side, `model_config(name, shapes)` and
+  `program_params(shapes, key)`;
+- `<family>_reference.py`: the plain reference, `Shapes`,
+  `shapes(config)`, `seed_key(seed)`, `weights(shapes, key)` and
+  `gaps(shapes, weights, tokens, targets, quant=None)`;
+- `<family>_costs.py`: the work the per-layer readers price,
+  `token_flops(shapes, ctx)` (model FLOPs of one token attending to `ctx`
+  positions, over every layer and the head) and
+  `attention_layers(shapes, ctx, block_tokens)` (one `(flops, bytes)` per
+  layer for one lane's decode attention: its live KV blocks, q in and out).
+
+A family that lacks one of them fails when its cell is resolved.
 """
 from __future__ import annotations
 
@@ -45,6 +58,7 @@ class Cell:
     mix: dict
     family: object             # models/<family>.py
     reference: object          # models/<family>_reference.py
+    costs: object              # models/<family>_costs.py
     shapes: object             # reference.Shapes
     end_to_end: List[dict]
     per_layer: List[dict]
@@ -60,16 +74,16 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
     entry = {x["name"]: x for x in bench["configs"]}[c["config"]]
     with open(root / entry["file"]) as f:
         config = json.load(f)
-    family = importlib.import_module(f"bench.models.{config['family']}")
-    reference = importlib.import_module(
-        f"bench.models.{config['family']}_reference")
+    family, reference, costs = (
+        importlib.import_module(f"bench.models.{config['family']}{part}")
+        for part in ("", "_reference", "_costs"))
 
     def mine(m):
         return "workloads" not in m or workload in m["workloads"]
     return Cell(
         name=workload, chips=c["chips"], config_name=c["config"],
         mix=traffic.load(c["traffic"]), family=family,
-        reference=reference, shapes=reference.shapes(config),
+        reference=reference, costs=costs, shapes=reference.shapes(config),
         end_to_end=[m for m in bench["end_to_end"] if mine(m)],
         per_layer=[m for m in bench["per_layer"] if mine(m)])
 
@@ -189,6 +203,7 @@ class Job:
 class Readout:
     """What the per-layer readers read."""
     shapes: object
+    costs: object                  # the family's models/<family>_costs.py
     peak: object
     block_tokens: int
     jobs: List[Job]
@@ -210,6 +225,17 @@ def run_job(srv, params, specs, disp: Dispatches) -> Job:
         t1 = time.perf_counter()
     return Job(specs, t0, t1, done, list(disp.times), list(srv.serve_log),
                list(srv.reports))
+
+
+def job_count(seconds: float, job: Job) -> int:
+    """How many identical jobs a window of `seconds` measures: the whole
+    number nearest to `seconds` over the job's length, at least one. The
+    length is the job's dispatches times their median interval, so that a
+    stall or the profiler's stop in a few windows cannot change the count,
+    and rounding puts the edges between counts at 2/3, 2/5, 2/7... of
+    `seconds` rather than at its halves and thirds."""
+    length = len(job.dispatch_t) * float(np.median(job.intervals()))
+    return max(1, round(seconds / length))
 
 
 def build(cell: Cell, seed: int):
@@ -255,14 +281,10 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, clock,
         disp.trace = (TRACE_FROM, TRACE_FROM + TRACE_WINDOWS, trace_dir)
     w0 = time.time()
     setup_s = w0 - process_start
-    jobs: List[Job] = []
-    start = time.perf_counter()
-    while True:
+    jobs = [run_job(srv, params, specs, disp)]
+    disp.stop()
+    for _ in range(job_count(seconds, jobs[0]) - 1):
         jobs.append(run_job(srv, params, specs, disp))
-        disp.stop()
-        elapsed = time.perf_counter() - start
-        if elapsed + jobs[-1].seconds > seconds:
-            break
     w1 = time.time()
     compiles = clock.count(w0, w1)
     if compiles:
@@ -282,7 +304,8 @@ def execute(cell: Cell, seed: int, seconds: float, trace: bool, clock,
         summary = trace_reduce.reduce(trace_reduce.find(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
         traced = (0, TRACE_FROM, TRACE_FROM + TRACE_WINDOWS)
-    readout = Readout(cell.shapes, peak, BLOCK_TOKENS, jobs, summary, traced)
+    readout = Readout(cell.shapes, cell.costs, peak, BLOCK_TOKENS, jobs,
+                      summary, traced)
     e2e = end_to_end(jobs, setup_s)
     if trace:
         metrics = {}

@@ -1,14 +1,17 @@
 """The Pallas paged_attention kernel's share of its roofline over the
-traced stretch. Least time: for each layer of each lane-step the kernel
-served, the larger of the bytes it needs over HBM bandwidth and its FLOPs
-over the bf16 peak, summed over the lanes of that call; the bytes are the live KV blocks of that lane (what
-the live-bytes gauge counts) plus q and out, so blocks that hold no live
-token are not counted and a kernel that skips them reads the same work.
-Memory bounds every call at these shapes (4 FLOPs per 2 bytes of KV).
-Kernel time: the summed device durations of the ops named after it."""
+traced stretch. Least time: for each layer of each step the kernel
+served, that layer call's FLOPs and bytes summed over its lanes (the
+family's `attention_layers`), and the larger of the bytes over HBM
+bandwidth and the FLOPs over the bf16 peak, summed over every layer call.
+A lane's bytes are its live KV blocks in that layer (what the live-bytes
+gauge counts, or fewer where a layer reads only a window) plus q and
+out, so blocks that hold no live token are not counted and a kernel that
+skips them reads the same work. Memory bounds every GLM call at these
+shapes (4 FLOPs per 2 bytes of KV). Kernel time: the summed device
+durations of the ops named after it."""
 from collections import defaultdict
 
-from bench import costs
+import numpy as np
 
 
 def read(r):
@@ -18,12 +21,12 @@ def read(r):
     if kernel_s <= 0:
         return None
     job, first, end = r.traced
-    calls = defaultdict(lambda: [0, 0])      # step -> [FLOPs, bytes]
+    calls = defaultdict(int)       # step -> [layers, 2] of (FLOPs, bytes)
     for step, pos, _ in r.jobs[job].lane_steps(first, end):
-        flops, nbytes = costs.attention_call(r.shapes, pos + 1,
-                                             r.block_tokens)
-        calls[step][0] += flops
-        calls[step][1] += nbytes
-    least = sum(max(b / r.peak.hbm_bytes_per_s, f / r.peak.bf16_flops)
-                for f, b in calls.values())
-    return 100.0 * least * r.shapes.layers / kernel_s
+        calls[step] = calls[step] + np.array(
+            r.costs.attention_layers(r.shapes, pos + 1, r.block_tokens),
+            dtype=np.float64)
+    least = sum(float(np.maximum(c[:, 1] / r.peak.hbm_bytes_per_s,
+                                 c[:, 0] / r.peak.bf16_flops).sum())
+                for c in calls.values())
+    return 100.0 * least / kernel_s

@@ -1,10 +1,13 @@
-"""Operations and bytes the served model needs, from the configuration's
-shapes alone. These are the numerators of `decode_mfu_pct` and
-`paged_attention_roofline`: what the algorithm needs, not what the
-program happens to do (dead blocks, idle lanes and overshoot steps are
-not counted).
+"""Operations and bytes the GLM family's served model needs, from the
+configuration's shapes alone. These are the numerators of
+`decode_mfu_pct` and `paged_attention_roofline`: what the algorithm
+needs, not what the program happens to do (dead blocks, idle lanes and
+overshoot steps are not counted).
 
-`Shapes` is the reference's own (bench/models/*_reference.py); only its
+A family's costs module gives the readers two functions, found by name
+like its program and reference modules (see bench/harness.py):
+`token_flops(s, ctx)` and `attention_layers(s, ctx, block_tokens)`.
+`Shapes` is the family reference's own (glm_reference.py); only its
 sizes are read here.
 """
 from __future__ import annotations
@@ -30,7 +33,7 @@ def attention_flops(s, ctx: int) -> int:
 
 def token_flops(s, ctx: int) -> int:
     """Model FLOPs of one token at a position that attends to `ctx`
-    positions (itself included)."""
+    positions (itself included), over every layer and the head."""
     return 2 * matmul_params(s) + attention_flops(s, ctx)
 
 
@@ -46,3 +49,9 @@ def attention_call(s, ctx: int, block_tokens: int):
     q_out = 2 * s.heads * s.head_dim * BF16
     flops = 4 * s.heads * s.head_dim * ctx
     return flops, blocks * kv_block_bytes(s, block_tokens) + q_out
+
+
+def attention_layers(s, ctx: int, block_tokens: int):
+    """[(FLOPs, bytes)] of one lane's decode attention, one entry per
+    layer: every GLM layer attends to all `ctx` positions alike."""
+    return [attention_call(s, ctx, block_tokens)] * s.layers

@@ -1,13 +1,19 @@
 """The benchmark's arithmetic against hand sums: end-to-end metrics from a
 hand-made job, the per-layer counters, the FLOP and byte counts at
-chatglm3-6b's shapes, the peak table and the traffic generator."""
+chatglm3-6b's shapes, a family's costs found by name, the peak table and
+the traffic generator."""
+import dataclasses
 import json
+import re
+import sys
+import types
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-from bench import check, costs, harness, peaks, traffic
+from bench import check, harness, peaks, traffic
+from bench.models import glm_costs as costs
 from bench.models import glm_reference as ref
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -40,8 +46,8 @@ def _job(t0=100.0):
 
 
 def _readout(jobs, trace=None, traced=None):
-    return harness.Readout(CHATGLM, peaks.lookup("TPU v5 lite"), 16, jobs,
-                           trace, traced)
+    return harness.Readout(CHATGLM, costs, peaks.lookup("TPU v5 lite"), 16,
+                           jobs, trace, traced)
 
 
 def test_latencies_read_the_freeing_dispatch():
@@ -94,6 +100,22 @@ def test_step_median_ignores_one_stalled_window():
         _readout([harness.Job([], 0.0, 1.0, [], [0.5], [], [])])) is None
 
 
+@pytest.mark.parametrize("seconds,stall,count", [
+    (45.0, 0.0, 2),       # a job of 22.5 s, half the window: two jobs
+    (45.0, 0.1, 2),       # 22.6 s: two, where a floor would flip to one
+    (45.0, 3.0, 2),       # a job stretched to 25.5 s by one stall
+    (44.0, 0.0, 2),       # just short of two jobs' length
+    (70.0, 0.0, 3),
+    (20.0, 0.0, 1),       # under one job: still one
+])
+def test_job_count_rounds_and_reads_past_stalls(seconds, stall, count):
+    t0 = 100.0
+    dispatch = [t0 + 0.375 * k for k in range(60)]
+    dispatch[30:] = [t + stall for t in dispatch[30:]]
+    job = harness.Job([], t0, dispatch[-1] + 0.375, [], dispatch, [], [])
+    assert harness.job_count(seconds, job) == count
+
+
 def test_trace_metrics_silent_without_a_trace():
     r = _readout([_job()])
     for name in ("device_idle_pct", "decode_mfu_pct",
@@ -129,12 +151,17 @@ def test_flop_and_byte_counts_by_hand():
     flops, nbytes = costs.attention_call(s, 33, 16)
     assert flops == 4 * 32 * 128 * 33
     assert nbytes == 3 * 16384 + 2 * 32 * 128 * 2
+    assert costs.attention_layers(s, 33, 16) == [(flops, nbytes)] * 28
+
+
+class T:                                         # a trace summary
+    window_s, busy_s = 2.0, 1.5
+    kernel_s = {"paged_attention": 0.01}
 
 
 def test_roofline_and_mfu_by_hand():
-    class T:                                     # a trace summary
-        window_s, busy_s = 2.0, 1.5
-        kernel_s = {"paged_attention": 0.01}
+    # the formulas the readers had before a family priced its own layers:
+    # one GLM layer call times 28, and token_flops of every useful lane-step
     job = _job()
     r = _readout([job], T(), (0, 0, 4))
     pk = peaks.lookup("TPU v5 lite")
@@ -147,12 +174,87 @@ def test_roofline_and_mfu_by_hand():
     least = sum(max(b / pk.hbm_bytes_per_s, f / pk.bf16_flops)
                 for f, b in calls.values()) * 28
     assert harness.reader("paged_attention_roofline")(r) == \
-        pytest.approx(100 * least / 0.01)
+        pytest.approx(100 * least / 0.01, rel=1e-9)
     useful = sum(costs.token_flops(CHATGLM, p + 1)
                  for _, p, u in job.lane_steps(0, 4) if u)
     assert harness.reader("decode_mfu_pct")(r) == \
-        pytest.approx(100 * useful / (2.0 * 197e12))
+        pytest.approx(100 * useful / (2.0 * 197e12), rel=1e-9)
     assert harness.reader("device_idle_pct")(r) == pytest.approx(25.0)
+
+
+@dataclasses.dataclass(frozen=True)
+class StubShapes:
+    layers: int
+    window: int
+    vocab: int
+
+
+def _register_family(monkeypatch, family, with_costs=True):
+    """A family of two layers, full attention then attention over a window
+    of the last `window` positions, registered in memory under
+    bench.models: no file of the benchmark names it or is touched."""
+    program = types.ModuleType(f"bench.models.{family}")
+    reference = types.ModuleType(f"bench.models.{family}_reference")
+    reference.shapes = lambda cfg: StubShapes(
+        cfg["num_layers"], cfg["window"], cfg["vocab_size"])
+    modules = [program, reference]
+    if with_costs:
+        stub_costs = types.ModuleType(f"bench.models.{family}_costs")
+        stub_costs.token_flops = lambda s, ctx: 1000 + 10 * ctx
+
+        def attention_layers(s, ctx, block_tokens):
+            seen = [ctx, min(ctx, s.window)]
+            return [(4 * ctx, 100 * -(-seen[0] // block_tokens)),
+                    (10**6 * seen[1], 100 * -(-seen[1] // block_tokens))]
+        stub_costs.attention_layers = attention_layers
+        modules.append(stub_costs)
+    for m in modules:
+        monkeypatch.setitem(sys.modules, m.__name__, m)
+    return modules
+
+
+def _stub_root(tmp_path, family):
+    """A checkout whose BENCHMARK.json has one cell of a `family` model on
+    the chat mix, with the real metric entries."""
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    bench["configs"] = [{"name": "stub-2l", "file": "stub.json"}]
+    bench["workloads"] = [{"name": "stub-2l.chat", "config": "stub-2l",
+                           "traffic": "chat", "chips": 1}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    (tmp_path / "stub.json").write_text(json.dumps(
+        {"family": family, "num_layers": 2, "window": 8,
+         "vocab_size": 256}))
+    return tmp_path
+
+
+def test_family_costs_found_by_name_price_each_layer(monkeypatch, tmp_path):
+    program, reference, stub_costs = _register_family(monkeypatch, "stub")
+    cell = harness.resolve("stub-2l.chat", _stub_root(tmp_path, "stub"))
+    assert (cell.family, cell.reference, cell.costs) == \
+        (program, reference, stub_costs)
+    assert cell.shapes == StubShapes(2, 8, 256)
+    # two lanes, each a 4-token prompt and 12 tokens, through window 0:
+    # positions 0..15, useful while pos < 15
+    job = harness.Job([traffic.Spec([1] * 4, 12)] * 2, 0.0, 1.0,
+                      [C([5] * 12, (0, 1))] * 2, [0.0], [], [])
+    r = harness.Readout(cell.shapes, cell.costs, peaks.lookup("TPU v5 lite"),
+                        4, [job], T(), (0, 0, 1))
+    # layer 0 reads ceil(ctx / 4) blocks of 100 bytes, 40 over ctx 1..16,
+    # bound by bytes; the windowed layer's 10**6 * min(ctx, 8) FLOPs, 100
+    # over ctx 1..16, bound by FLOPs; both lanes at every step
+    least = 2 * 100 * 40 / 819e9 + 2 * 10**6 * 100 / 197e12
+    assert harness.reader("paged_attention_roofline")(r) == \
+        pytest.approx(100 * least / 0.01, rel=1e-12)
+    # 15 useful steps a lane: 2 * (15 * 1000 + 10 * (1 + ... + 15))
+    assert harness.reader("decode_mfu_pct")(r) == \
+        pytest.approx(100 * 2 * 16_200 / (2.0 * 197e12), rel=1e-12)
+
+
+def test_family_without_costs_is_refused(monkeypatch, tmp_path):
+    _register_family(monkeypatch, "nocosts", with_costs=False)
+    with pytest.raises(ModuleNotFoundError,
+                       match=re.escape("bench.models.nocosts_costs")):
+        harness.resolve("stub-2l.chat", _stub_root(tmp_path, "nocosts"))
 
 
 def test_peak_table_refuses_unknown_kind():

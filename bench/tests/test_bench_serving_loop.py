@@ -33,8 +33,8 @@ def _job(t0, ready, dispatch, useful, admitted):
 
 
 def _readout(jobs):
-    return harness.Readout(None, peaks.lookup("TPU v5 lite"), 16, jobs,
-                           None, None)
+    return harness.Readout(None, None, peaks.lookup("TPU v5 lite"), 16,
+                           jobs, None, None)
 
 
 def test_readers_by_hand():
