@@ -24,6 +24,19 @@ SHARED_ATTN = "shared_attn"  # zamba2's shared attention block (tied params)
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling (DeepSeek-V2's `rope_scaling`, type "yarn"):
+    interpolated frequencies below the beta_slow..beta_fast correction
+    range, and the attention scale's mscale (models/mla.py)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class HadesConfig:
     """Frontend (paper technique) configuration."""
     enabled: bool = True
@@ -86,6 +99,20 @@ class ModelConfig:
     num_experts: int = 0             # 0 = dense FFN
     experts_per_token: int = 0
     moe_d_ff: int = 0                # expert hidden dim (olmoe: 1024)
+    n_shared_experts: int = 0        # always-on experts, one MLP of n x moe_d_ff
+    norm_topk_prob: bool = True      # renormalise the top-k gate weights
+    routed_scaling_factor: float = 1.0   # scales them when not renormalised
+    # (first, count): the router's experts this chip holds — a drop-free
+    # decode layer computes their part of the result (models/moe.py,
+    # `held_experts_block`); () keeps the capacity-dispatch `moe_block`
+    held_experts: Tuple[int, ...] = ()
+    first_k_dense: int = 0           # leading layers with a dense FFN of d_ff
+    # --- latent attention (MLA, DeepSeek-V2; 0 = off) ---
+    kv_lora_rank: int = 0            # latent width cached per token
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_scaling: Optional[YarnScaling] = None
     # --- encoder-decoder ---
     is_encoder_decoder: bool = False
     num_encoder_layers: int = 0

@@ -143,20 +143,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 # paged_attention
 # ---------------------------------------------------------------------------
-@jax.jit
+@functools.partial(jax.jit, static_argnames=("scale", "v_dim"))
 def paged_attention(q: jax.Array, kv_pages: jax.Array,
-                    block_tables: jax.Array, seq_lens: jax.Array
+                    block_tables: jax.Array, seq_lens: jax.Array, *,
+                    scale: float = None, v_dim: int = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """q: [B,H,D]; kv_pages: [n_slots, 2, KV, bt, D] (the paged KV pool,
-    read in place); block_tables: [B, MB]; seq_lens: [B].
-    Returns (out [B,H,D], touched [B,MB] bool)."""
+    read in place), or [n_slots, 1, KV, bt, D] with `v_dim` (K=V tiles);
+    block_tables: [B, MB]; seq_lens: [B]; `scale` on the scores, default
+    D^-0.5. Returns (out [B,H,v_dim or D], touched [B,MB] bool)."""
     b, h, d = q.shape
     kv = kv_pages.shape[2]
     qg = q.reshape(b, kv, h // kv, d)
     out, touched = _pa.paged_attention_pallas(
-        qg, kv_pages, block_tables, seq_lens, scale=d ** -0.5,
+        qg, kv_pages, block_tables, seq_lens,
+        scale=d ** -0.5 if scale is None else scale, v_dim=v_dim,
         interpret=_interpret())
-    return out.reshape(b, h, d), touched.astype(bool)
+    return out.reshape(b, h, v_dim or d), touched.astype(bool)
 
 
 # ---------------------------------------------------------------------------

@@ -11,6 +11,12 @@ first, then kv head), so the pool is `kv_pages` [n_slots, 2, KV, bt, D].
 It stays in HBM (`memory_space=ANY`); one slot — K and V of every kv head
 — is one contiguous DMA into VMEM. No K/V split or relayout of the pool.
 
+K=V mode (`v_dim`, static): latent attention (models/mla.py) caches one
+vector a token, so a slot is [1, KV, bt, D] and its one tile is read as K
+and, in its first `v_dim` columns, as V; the output is [.., v_dim]. The
+walk, the DMAs, the softmax and the access bits are the same; without
+`v_dim` the kernel is the separate-tile kernel it was.
+
 Grid and walk: the grid runs over lanes only, `grid = (B,)`. Lane b has
 `n_live = ceil(seq_lens[b] / bt)` live blocks, and its step loops over
 `ceil(n_live / P)` chunks of P slots (a dynamic trip count), where P is
@@ -58,7 +64,7 @@ def _chunk_slots(max_blocks: int, block_tokens: int) -> int:
 
 def _kernel(tbl_ref, lens_ref, q_ref, kv_hbm, o_ref, touched_ref,
             buf, sems, buf_idx, *, block_tokens: int, n_blocks: int,
-            n_chunk_slots: int, scale: float):
+            n_chunk_slots: int, scale: float, v_dim=None):
     bt, mb, p = block_tokens, n_blocks, n_chunk_slots
     _, _, _, kv, _, d = buf.shape
     rep = q_ref.shape[2]
@@ -139,7 +145,10 @@ def _kernel(tbl_ref, lens_ref, q_ref, kv_hbm, o_ref, touched_ref,
             k = buf[half, :, 0, h].astype(jnp.float32).reshape(p * bt, d)
             # a slot not fetched holds stale VMEM: zero its V, so that its
             # zero weights cannot meet a NaN there
-            v = buf[half, :, 1, h].astype(jnp.float32)
+            if v_dim is None:
+                v = buf[half, :, 1, h].astype(jnp.float32)
+            else:
+                v = buf[half, :, 0, h, :, :v_dim].astype(jnp.float32)
             v = jnp.concatenate([jnp.where(fetch, v[i], 0.0)
                                  for i, fetch in enumerate(fetched)])
             s = jax.lax.dot_general(q[h], k, (((1,), (1,)), ((), ())),
@@ -157,7 +166,8 @@ def _kernel(tbl_ref, lens_ref, q_ref, kv_hbm, o_ref, touched_ref,
 
     init = tuple((jnp.full((rep, 1), NEG_INF, jnp.float32),
                   jnp.zeros((rep, 1), jnp.float32),
-                  jnp.zeros((rep, d), jnp.float32)) for _ in range(kv))
+                  jnp.zeros((rep, v_dim or d), jnp.float32))
+                 for _ in range(kv))
     half, heads = jax.lax.fori_loop(0, n_chunks, chunk_body,
                                     (buf_idx[0], init))
     buf_idx[0] = half
@@ -176,14 +186,18 @@ def _kernel(tbl_ref, lens_ref, q_ref, kv_hbm, o_ref, touched_ref,
 
 def paged_attention_pallas(q: jax.Array, kv_pages: jax.Array,
                            block_tables: jax.Array, seq_lens: jax.Array,
-                           *, scale: float = None, interpret: bool = True):
+                           *, scale: float = None, v_dim: int = None,
+                           interpret: bool = True):
     """q: [B, KV, REP, D]; kv_pages: [n_slots, 2, KV, bt, D] (K at index
-    0, V at 1 of axis 1); block_tables: [B, MB] int32 physical slot ids
-    (-1 unused); seq_lens: [B] int32, at most MB * bt.
-    Returns (out [B, KV, REP, D], touched [B, MB] int32)."""
+    0, V at 1 of axis 1), or with `v_dim` [n_slots, 1, KV, bt, D] (one
+    tile: K, and V in its first v_dim columns); block_tables: [B, MB]
+    int32 physical slot ids (-1 unused); seq_lens: [B] int32, at most
+    MB * bt. Returns (out [B, KV, REP, v_dim or D], touched [B, MB]
+    int32)."""
     b, kv, rep, d = q.shape
     n_slots, two, kv2, bt, d2 = kv_pages.shape
-    assert (two, kv, d) == (2, kv2, d2)
+    assert (two, kv, d) == (1 if v_dim else 2, kv2, d2)
+    dv = v_dim or d
     mb = block_tables.shape[1]
     p = _chunk_slots(mb, bt)
 
@@ -195,25 +209,25 @@ def paged_attention_pallas(q: jax.Array, kv_pages: jax.Array,
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, kv, rep, d), lambda i, tbl, lens: (i, 0, 0, 0)),
+            pl.BlockSpec((1, kv, rep, dv), lambda i, tbl, lens: (i, 0, 0, 0)),
             # the access bits are scalars: the whole [B, MB] map stays in
             # SMEM for the kernel's lifetime
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         scratch_shapes=[
-            pltpu.VMEM((2, p, 2, kv, bt, d), kv_pages.dtype),
+            pltpu.VMEM((2, p, two, kv, bt, d), kv_pages.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kern = functools.partial(
         _kernel, block_tokens=bt, n_blocks=mb, n_chunk_slots=p,
-        scale=scale if scale is not None else d ** -0.5)
+        scale=scale if scale is not None else d ** -0.5, v_dim=v_dim)
     out, touched = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, kv, rep, d), q.dtype),
+            jax.ShapeDtypeStruct((b, kv, rep, dv), q.dtype),
             jax.ShapeDtypeStruct((b, mb), jnp.int32),
         ],
         # a lane's step prefetches the next lane's first chunk: sequential

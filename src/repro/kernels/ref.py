@@ -72,26 +72,34 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # paged_attention — decode through the object table (block-paged KV)
 # ---------------------------------------------------------------------------
 def paged_attention(q: jax.Array, kv_pages: jax.Array,
-                    block_tables: jax.Array, seq_lens: jax.Array
+                    block_tables: jax.Array, seq_lens: jax.Array, *,
+                    scale: Optional[float] = None,
+                    v_dim: Optional[int] = None
                     ) -> Tuple[jax.Array, jax.Array]:
     """q: [B,H,D] one token per sequence.
     kv_pages: [n_slots, 2, KV, block_tokens, D] — the HadesPool data (K at
-    index 0 of axis 1, V at 1). block_tables: [B, max_blocks] physical
-    slot per logical KV block (-1 = unused). seq_lens: [B].
-    Returns (out [B,H,D], touched [B, max_blocks] bool — the access bits
-    the fused tracking would record)."""
+    index 0 of axis 1, V at 1); with `v_dim`, [n_slots, 1, KV, bt, D]: one
+    tile read as K and, in its first v_dim columns, as V. block_tables:
+    [B, max_blocks] physical slot per logical KV block (-1 = unused).
+    seq_lens: [B]. `scale` on the scores, default D^-0.5.
+    Returns (out [B,H,v_dim or D], touched [B, max_blocks] bool — the
+    access bits the fused tracking would record)."""
     b, h, d = q.shape
     n_slots, _, kv, bt, _ = kv_pages.shape
     mb = block_tables.shape[1]
     safe = jnp.maximum(block_tables, 0)
-    pages = kv_pages[safe]                  # [B, mb, 2, KV, bt, D]
+    pages = kv_pages[safe]                  # [B, mb, 2|1, KV, bt, D]
     k = pages[:, :, 0].transpose(0, 1, 3, 2, 4).reshape(b, mb * bt, kv, d)
-    v = pages[:, :, 1].transpose(0, 1, 3, 2, 4).reshape(b, mb * bt, kv, d)
+    if v_dim is None:
+        v = pages[:, :, 1].transpose(0, 1, 3, 2, 4).reshape(b, mb * bt,
+                                                            kv, d)
+    else:
+        v = k[..., :v_dim]
     pos = jnp.arange(mb * bt)[None]
     valid = (pos < seq_lens[:, None]) & \
         (jnp.repeat(block_tables >= 0, bt, axis=1))
     out, m, l = attn_lib.decode_attention_partial(
-        q[:, None], k, v, valid)
+        q[:, None], k, v, valid, scale=scale)
     out = out / jnp.moveaxis(jnp.maximum(l, 1e-30), 1, -1)[..., None]
     n_blocks_used = (seq_lens + bt - 1) // bt
     touched = (jnp.arange(mb)[None] < n_blocks_used[:, None]) & \

@@ -162,17 +162,17 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
-def decode_attention_partial(q, k_part, v_part, valid_mask):
+def decode_attention_partial(q, k_part, v_part, valid_mask, scale=None):
     """Flash-decoding partial: attention over a shard of the KV sequence.
-    Returns (unnormalized_out [B,1,H,D] fp32, m [B,H,1], l [B,H,1]) so that
-    shards combine with `combine_partials` (psum-style merge).
-    valid_mask: [B, S_part] bool."""
+    Returns (unnormalized_out [B,1,H,Dv] fp32, m [B,H,1], l [B,H,1]) so
+    that shards combine with `combine_partials` (psum-style merge).
+    valid_mask: [B, S_part] bool; `scale` on the scores, default D^-0.5."""
     b, _, h, d = q.shape
     _, s, kv, _ = k_part.shape
     k = _expand_kv(k_part, h // kv)
     v = _expand_kv(v_part, h // kv)
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
-    scores *= d ** -0.5
+    scores *= d ** -0.5 if scale is None else scale
     scores = jnp.where(valid_mask[:, None, None, :], scores, NEG_INF)
     m = jnp.max(scores, axis=-1)                  # [B,H,1]
     p = jnp.exp(scores - m[..., None])

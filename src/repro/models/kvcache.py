@@ -35,6 +35,8 @@ from repro.core import object_table as ot
 from repro.core import pool as pl
 from repro.kernels import ops as kops
 
+LANE = 128      # a TPU vreg's lanes: the minor tile of an HBM array
+
 
 @dataclasses.dataclass(frozen=True)
 class KVCacheConfig:
@@ -47,6 +49,10 @@ class KVCacheConfig:
     dtype: str = "bfloat16"
     sb_slots: int = 16       # superblock granularity (blocks per madvise)
     slack: float = 1.5
+    # 0: a slot holds K and V as separate tiles. > 0: latent attention's
+    # one vector a token (models/mla.py), cached once and read as K, its
+    # first v_dim columns as V
+    v_dim: int = 0
 
     @property
     def max_objects(self) -> int:
@@ -56,8 +62,20 @@ class KVCacheConfig:
     def slot_shape(self) -> Tuple[int, int, int, int]:
         """One KV block's slot: K/V, kv head, token, head dim. The pool
         data is then the paged-attention kernel's `kv_pages` as it
-        stands: its tiled dims are (block_tokens, head_dim)."""
-        return (2, self.num_kv_heads, self.block_tokens, self.head_dim)
+        stands: its tiled dims are (block_tokens, head_dim). A latent
+        slot (`v_dim`) holds one K=V tile, (1, kv heads, bt, `slot_dim`)."""
+        return (1 if self.v_dim else 2, self.num_kv_heads,
+                self.block_tokens, self.slot_dim)
+
+    @property
+    def slot_dim(self) -> int:
+        """A tile's last dim: head_dim, or for a latent slot head_dim
+        rounded up to the 128-lane tile. HBM pads it so anyway, and the
+        kernel's DMA can only move whole tiles of it (576 -> 640); the pad
+        columns hold zeros in the pool and in q, so scores are unchanged."""
+        if not self.v_dim:
+            return self.head_dim
+        return -(-self.head_dim // LANE) * LANE
 
     @property
     def slot_words(self) -> int:
@@ -121,8 +139,9 @@ def append(cfg: KVCacheConfig, state: Dict, k: jax.Array, v: jax.Array
 
 @jax.named_scope("kv_append")
 def append_layer(cfg: KVCacheConfig, state: Dict, layer, k: jax.Array,
-                 v: jax.Array) -> Dict:
-    """k/v: [B, KV, D] — ONE layer's k/v for the current token, for the
+                 v: Optional[jax.Array] = None) -> Dict:
+    """k/v: [B, KV, D] — ONE layer's k/v for the current token (a latent
+    slot, `cfg.v_dim`, takes the one K=V vector as k and no v), for the
     server's fused per-layer decode transition (qkv -> append -> attend
     with `h` advanced through each layer, which `append` cannot express:
     it needs all layers' k/v up front). `layer` may be a traced index
@@ -153,12 +172,13 @@ def append_layer(cfg: KVCacheConfig, state: Dict, layer, k: jax.Array,
 
     words = pool["table"][jnp.minimum(obj, cfg.max_objects - 1)]
     slots = ot.slot_of(words).astype(jnp.int32)         # [B]
-    data = pool["data"]                       # [n_slots + 1, 2, KV, bt, D]
+    data = pool["data"]                       # [n_slots + 1, 2|1, KV, bt, D]
     # overflow/inactive lanes write ZEROS onto the pool's all-zero
     # scratch row (its invariant holds), never onto a live slot
     slots = jnp.where(fits, slots, pcfg.n_slots)
     kv_tok = jnp.where(fits[:, None, None, None],
-                       jnp.stack([k, v], axis=1), 0).astype(data.dtype)
+                       _pad_to_slot(cfg, k)[:, None] if cfg.v_dim
+                       else jnp.stack([k, v], axis=1), 0).astype(data.dtype)
     # one in-place row write per lane: a batched scatter at a token
     # offset inside the tiled (bt, D) dims makes XLA:TPU copy the whole
     # pool into another layout and back around it, in every layer
@@ -221,8 +241,11 @@ def admit_lanes(state: Dict, lanes: jax.Array) -> Dict:
 # ---------------------------------------------------------------------------
 def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
            *, seq_lens: Optional[jax.Array] = None,
-           use_pallas: Optional[bool] = None) -> Tuple[jax.Array, Dict]:
-    """q: [B, H, D] -> (out [B, H, D], state with access recorded).
+           use_pallas: Optional[bool] = None,
+           scale: Optional[float] = None) -> Tuple[jax.Array, Dict]:
+    """q: [B, H, D] -> (out [B, H, D], state with access recorded); a
+    latent slot (`cfg.v_dim`) gives out [B, H, v_dim]. `scale` multiplies
+    the scores (default D^-0.5).
     `layer` may be a traced index (the server's decode layer scan).
     `seq_lens` defaults to state["pos"] — correct when the caller has
     already advanced pos past the appended token (`append`); the
@@ -236,7 +259,7 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
     correctness-only and orders of magnitude too slow for the serving
     hot path (tests/test_kernels.py keeps the two bit-compatible on the
     touched bits and within fp tolerance on the outputs). Both read the
-    pool data in place as [n_slots + 1, 2, KV, bt, D] pages."""
+    pool data in place as [n_slots + 1, 2|1, KV, bt, D] pages."""
     pcfg = cfg.pool_config()
     pool = state["pool"]
     tbl = state["block_tables"][layer]               # [B, MB] logical ids
@@ -248,12 +271,18 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
     lens = jnp.where(state["active"], lens, 0)
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    if cfg.v_dim:
+        q = _pad_to_slot(cfg, q)
+        scale = cfg.head_dim ** -0.5 if scale is None else scale
 
+    v_dim = cfg.v_dim or None
     if use_pallas:
-        out, touched = kops.paged_attention(q, pool["data"], slots, lens)
+        out, touched = kops.paged_attention(q, pool["data"], slots, lens,
+                                            scale=scale, v_dim=v_dim)
     else:
         from repro.kernels import ref as kref
-        out, touched = kref.paged_attention(q, pool["data"], slots, lens)
+        out, touched = kref.paged_attention(q, pool["data"], slots, lens,
+                                            scale=scale, v_dim=v_dim)
 
     # inactive lanes really do return ZEROS: with lens == 0 the Pallas
     # kernel writes zeros, but the oracle's all-masked softmax degenerates
@@ -265,6 +294,12 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
                             tbl, -1).reshape(-1)
     pool = _record_touched(pcfg, pool, touched_ids)
     return out, dict(state, pool=pool)
+
+
+def _pad_to_slot(cfg: KVCacheConfig, x: jax.Array) -> jax.Array:
+    """[..., head_dim] -> [..., slot_dim], zeros in the pad columns."""
+    pad = cfg.slot_dim - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
 def _record_touched(pcfg: pl.PoolConfig, pool: Dict, obj_ids: jax.Array

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.models import layers as L
 
 # ---------------------------------------------------------------------------
 # Optional activation-sharding hints (§Perf cell A). jit in_shardings only
@@ -160,6 +161,53 @@ def moe_block_gathered(p: dict, x: jax.Array, cfg: ModelConfig
     out = jnp.einsum("tk,tkd->td", topk_w.astype(y.dtype), y)
     counts = jnp.zeros((e,), jnp.int32).at[topk_e.reshape(-1)].add(1)
     return out.reshape(b, s, d), jnp.zeros((), jnp.float32), counts
+
+
+def held_experts_block(p: dict, x: jax.Array, cfg: ModelConfig,
+                       lanes=None) -> Tuple[jax.Array, jax.Array]:
+    """Decode expert layer of a chip that holds `cfg.held_experts` =
+    (first, count) of the router's `num_experts` (expert parallelism;
+    on one chip the exchange is absent). The router scores all of them
+    and keeps the top k; every held expert runs on every row, weighted by
+    its gate where the row picked it and by zero elsewhere and on
+    inactive lanes (`lanes` [B] bool). Exact, and no token is dropped: at
+    decode batch sizes each held expert's weights are read once whatever
+    the routing, so this costs what a grouped matmul would. Gates follow
+    `norm_topk_prob` (renormalised over the k) or else are scaled by
+    `routed_scaling_factor`; `n_shared_experts` add one always-on MLP
+    (`p["shared"]`), once.
+
+    p: {"router" [D, E], "wi", "wg" [count, D, F], "wo" [count, F, D],
+    "shared": {"wi", "wg", "wo"}}. x: [B, S, D] -> (out [B, S, D], picks
+    [count] int32: top-k picks of active rows per held expert). Scopes
+    `router`, `experts` and `shared_experts` label its ops."""
+    b, s, d = x.shape
+    first, count = cfg.held_experts
+    xf = x.reshape(b * s, d)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("td,de->te", xf.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                               cfg.experts_per_token)           # [T, k]
+        if cfg.norm_topk_prob:
+            w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        else:
+            w = w * cfg.routed_scaling_factor
+        hit = (idx[..., None] - first) == jnp.arange(count)     # [T, k, n]
+        if lanes is not None:
+            hit &= jnp.repeat(lanes, s)[:, None, None]
+        gates = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)
+        picks = jnp.sum(hit, axis=(0, 1), dtype=jnp.int32)
+    with jax.named_scope("experts"):
+        h = jnp.einsum("td,edf->etf", xf, p["wi"])
+        g = jnp.einsum("td,edf->etf", xf, p["wg"])
+        h = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * h
+        y = jnp.einsum("etf,efd->etd", h, p["wo"])
+        out = jnp.einsum("te,etd->td", gates, y.astype(jnp.float32))
+    if "shared" in p:
+        with jax.named_scope("shared_experts"):
+            out = out + L.mlp(p["shared"], xf, True).astype(jnp.float32)
+    return out.astype(x.dtype).reshape(b, s, d), picks
 
 
 def moe_block_ref(p: dict, x: jax.Array, cfg: ModelConfig) -> jax.Array:

@@ -21,6 +21,7 @@ from repro.configs.base import (ATTN, MAMBA1, MAMBA2, SHARED_ATTN,
                                 ModelConfig)
 from repro.models import attention as attn_lib
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 
@@ -152,7 +153,7 @@ def _pos2d(positions, b, s):
 # Decode-mode attention block
 # ---------------------------------------------------------------------------
 def decode_layer_step(p: dict, x: jax.Array, cfg: ModelConfig, positions,
-                      attend_fn, enc_kv=None):
+                      attend_fn, enc_kv=None, lanes=None):
     """One decoder layer of single-token decode — THE single place the
     layer math lives, with the KV mechanics supplied by the caller:
     `attn_block_decode` plugs in the dense ring cache, the paged server
@@ -166,16 +167,31 @@ def decode_layer_step(p: dict, x: jax.Array, cfg: ModelConfig, positions,
     q [B,1,H,Dh], k/v [B,1,KV,Dh]; `aux` is whatever cache/pool state the
     caller must thread onward. Returns (x', aux, expert_counts).
 
+    Latent attention (`cfg.kv_lora_rank`, models/mla.py) hands attend_fn
+    the absorbed q [B,1,H,C+rope] and the token's one cached vector as k
+    [B,1,1,C+rope], with v None; attend_fn returns attention over the
+    latents [B,1,H,C], which W_UV maps to the heads' values.
+
+    The FFN is a layer's own: a dense MLP where `p` has "ffn" (a leading
+    dense layer), else the drop-free `held_experts_block` when the chip
+    holds a share of the experts (`cfg.held_experts`; `lanes` [B] bool
+    masks inactive lanes out of its gates, and expert_counts are the
+    picks per held expert), else the capacity-dispatch `moe_block`.
+
     Named scopes `qkv`, `attention` (attend_fn and the output projection)
     and `ffn` label the layer's ops in the compiled HLO's op_name."""
     b = x.shape[0]
     hd = cfg.resolved_head_dim
     with jax.named_scope("qkv"):
         h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
-        q, k, v = _qkv(p, h, cfg, positions)
+        if cfg.kv_lora_rank:
+            q, k, v = mla.qkv(p, h, cfg, positions)
+        else:
+            q, k, v = _qkv(p, h, cfg, positions)
     with jax.named_scope("attention"):
         o, aux = attend_fn(q, k, v)
-        x = x + jnp.einsum("bse,ed->bsd", o.reshape(b, 1, -1), p["wo"])
+        o = mla.values(p, o) if cfg.kv_lora_rank else o.reshape(b, 1, -1)
+        x = x + jnp.einsum("bse,ed->bsd", o, p["wo"])
 
     if enc_kv is not None:
         hx = L.rms_norm(x, p["ln_x"], cfg.norm_eps)
@@ -187,7 +203,11 @@ def decode_layer_step(p: dict, x: jax.Array, cfg: ModelConfig, positions,
     with jax.named_scope("ffn"):
         h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
         counts = jnp.zeros((max(cfg.num_experts, 1),), jnp.int32)
-        if cfg.num_experts:
+        if "ffn" in p:
+            f = L.mlp(p["ffn"], h2, cfg.mlp_gated)
+        elif cfg.held_experts:
+            f, counts = moe_lib.held_experts_block(p["moe"], h2, cfg, lanes)
+        elif cfg.num_experts:
             t = h2.shape[0] * h2.shape[1]
             if cfg.hades.expert_gather_decode and \
                     t * cfg.experts_per_token < cfg.num_experts:
@@ -196,8 +216,6 @@ def decode_layer_step(p: dict, x: jax.Array, cfg: ModelConfig, positions,
                 f, _, counts = moe_lib.moe_block_gathered(p["moe"], h2, cfg)
             else:
                 f, _, counts = moe_lib.moe_block(p["moe"], h2, cfg)
-        else:
-            f = L.mlp(p["ffn"], h2, cfg.mlp_gated)
         return x + f, aux, counts
 
 
@@ -239,6 +257,10 @@ def init_lm(cfg: ModelConfig, key) -> dict:
     of a whole stacked weight is ever materialised (an eager chatglm3-6b
     init would hold a 6.3 GB f32 draw and its scaled copy beside the
     bf16 weights)."""
+    if cfg.kv_lora_rank or cfg.first_k_dense:
+        raise NotImplementedError(
+            "init_lm builds uniform GQA layers; latent attention and a "
+            "leading dense segment take their parameters from the caller")
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, 8)
     n_attn = sum(1 for k in cfg.blocks if k == ATTN)

@@ -60,6 +60,7 @@ from repro.core import engine as eng
 from repro.core import pool as pl
 from repro.models import kvcache as kvc
 from repro.models import layers as L
+from repro.models import mla
 from repro.models import transformer as T
 from repro.runtime import sampling
 
@@ -165,11 +166,17 @@ class Server:
         self.model = model
         self.cfg = cfg
         mc = model.cfg
+        if mc.kv_lora_rank:     # latent attention: one K=V vector a token
+            kv_heads, head_dim, v_dim = \
+                1, mc.kv_lora_rank + mc.qk_rope_head_dim, mc.kv_lora_rank
+        else:
+            kv_heads, head_dim, v_dim = \
+                mc.num_kv_heads, mc.resolved_head_dim, 0
         self.kv_cfg = kvc.KVCacheConfig(
             num_layers=mc.num_layers, batch=cfg.batch,
             max_blocks=-(-cfg.max_len // cfg.block_tokens),
-            block_tokens=cfg.block_tokens, num_kv_heads=mc.num_kv_heads,
-            head_dim=mc.resolved_head_dim, dtype=mc.dtype)
+            block_tokens=cfg.block_tokens, num_kv_heads=kv_heads,
+            head_dim=head_dim, dtype=mc.dtype, v_dim=v_dim)
         self.col_cfg = col.CollectorConfig(use_pallas=cfg.use_pallas)
         self.backend = be.make(cfg.backend, **(cfg.backend_params or {}))
         self.reports: List[Dict] = []
@@ -183,31 +190,50 @@ class Server:
         Layers run under lax.scan; each layer derives qkv from the CURRENT
         residual stream (exactly once), appends its k/v to the paged pool
         and attends through the object table. Inactive lanes append
-        nothing and attend over zero keys (kvcache's lane mask)."""
+        nothing and attend over zero keys (kvcache's lane mask).
+
+        A model with `first_k_dense` leading dense layers runs them first,
+        from `params["dense_layers"]`, as a scan of their own at KV layer
+        indices [0, k); `params["layers"]` then scan at [k, num_layers).
+        A model that holds a share of the experts (`held_experts`) adds
+        its MoE layers' top-k picks on held experts of active lanes to
+        `state["expert_picks"]` (`reset` seeds it)."""
         mc: ModelConfig = self.model.cfg
         cfg = self.kv_cfg
         x = L.embed(params["embed"], tok)[:, None, :]   # [B,1,D]
         positions = state["pos"][:, None]               # [B,1]
+        scale = mla.softmax_scale(mc) if mc.kv_lora_rank else None
+        counted = bool(mc.held_experts)
 
         def layer_body(carry, xs):
             h, st = carry
             li, lp = xs
 
             def attend(q, k, v):
-                st2 = kvc.append_layer(cfg, st, li, k[:, 0], v[:, 0])
+                st2 = kvc.append_layer(cfg, st, li, k[:, 0],
+                                       None if v is None else v[:, 0])
                 # pos still points AT the appended token (advance_pos
                 # runs after the layer scan) -> the token attends to
                 # itself via pos + 1
                 out, st3 = kvc.attend(cfg, st2, li, q[:, 0],
-                                      seq_lens=st2["pos"] + 1)
+                                      seq_lens=st2["pos"] + 1, scale=scale)
                 return out[:, None], st3                # [B,1,H,Dh]
 
-            h, st, _ = T.decode_layer_step(lp, h, mc, positions, attend)
+            h, st, counts = T.decode_layer_step(lp, h, mc, positions, attend,
+                                                lanes=st["active"])
+            if counted and "moe" in lp:
+                st = dict(st,
+                          expert_picks=st["expert_picks"] + jnp.sum(counts))
             return (h, st), None
 
+        k = mc.first_k_dense
+        if k:
+            (x, state), _ = jax.lax.scan(
+                layer_body, (x, state), (jnp.arange(k),
+                                         params["dense_layers"]))
         (h, state), _ = jax.lax.scan(
             layer_body, (x, state),
-            (jnp.arange(mc.num_layers), params["layers"]))
+            (jnp.arange(k, mc.num_layers), params["layers"]))
         state = kvc.advance_pos(state)
         with jax.named_scope("logits"):
             h = L.rms_norm(h, params["final_ln"], mc.norm_eps)
@@ -294,6 +320,8 @@ class Server:
             gauges = {"rss_bytes": pl.rss_bytes(self.kv_cfg.pool_config(),
                                                 kv["pool"]),
                       "live_blocks": _live_blocks(kv)}
+            if "expert_picks" in kv:
+                gauges["expert_picks"] = kv["expert_picks"]
             return carry, outs, reports, gauges
 
         def step_apply(params, carry, tok, do_arm, do_collect,
@@ -492,7 +520,10 @@ class Server:
         `t_tokens` (the sampled tokens are on the host) and `host_ms`,
         the milliseconds of each host phase (schedule, inputs, dispatch,
         reports, tokens, lanes, log), each also a profiler span
-        `serve.<phase>` inside the step span `serve.window`.
+        `serve.<phase>` inside the step span `serve.window`. A model that
+        holds a share of the experts adds the window's `expert_picks`
+        (`_model_step`) and `experts_read`, the held experts whose weights
+        its MoE layers read: all of them, each layer and step.
 
         Returns one `Completion` per request, in submission order."""
         w = self.cfg.window or self.cfg.collect_every
@@ -531,6 +562,12 @@ class Server:
                 -(-(len(r.prompt) + r.max_new) // w) + 1 for r in requests)
         overlap = self.cfg.overlap_collect
         slot_bytes = self.kv_cfg.pool_config().slot_bytes
+        mc = self.model.cfg
+        # the drop-free expert layer reads every held expert, each MoE
+        # layer and step
+        experts_read = mc.held_experts[1] * w * (
+            mc.num_layers - mc.first_k_dense) if mc.held_experts else None
+        picks_seen = 0
         window_idx = 0
         pending = None
         while True:
@@ -657,6 +694,12 @@ class Server:
                         "t_tokens": t_tokens,
                         "host_ms": host_ms,   # "log" lands on exit
                     })
+                    if experts_read is not None:
+                        picks = int(gauges["expert_picks"])
+                        self.serve_log[-1].update(
+                            expert_picks=picks - picks_seen,
+                            experts_read=experts_read)
+                        picks_seen = picks
         if pending is not None:
             self.reports.extend(eng.window_reports(pending))
         assert all(r is not None for r in results)
@@ -703,6 +746,8 @@ class Server:
         events)."""
         self.state = kvc.init(self.kv_cfg, backend=self.backend,
                               active=active)
+        if self.model.cfg.held_experts:
+            self.state["expert_picks"] = jnp.zeros((), jnp.int32)
         self._steps = 0
         self._last_tok = jnp.zeros((self.cfg.batch,), jnp.int32)
         self._key = jax.random.PRNGKey(0)

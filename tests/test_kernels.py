@@ -95,22 +95,29 @@ def test_flash_attention_sweep(b, s, h, kv, d, causal, window, dtype):
 # ---------------------------------------------------------------------------
 # paged_attention
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("b,h,kv,d,bt,mb,lens", [
-    pytest.param(2, 8, 2, 16, 4, 6, None, id="2-8-2-16-4-6"),
-    pytest.param(3, 4, 4, 32, 8, 4, None, id="3-4-4-32-8-4"),
-    pytest.param(1, 8, 1, 64, 16, 3, None, id="1-8-1-64-16-3"),
-    pytest.param(4, 32, 2, 128, 16, 64, None, id="serving-shape"),
-    pytest.param(4, 8, 2, 16, 4, 6, (0, 9, 0, 24), id="empty-lanes"),
-    pytest.param(3, 8, 2, 32, 8, 8, (8, 16, 40), id="block-boundary"),
-    pytest.param(3, 4, 2, 16, 4, 6, (24, 24, 1), id="full-lanes"),
+@pytest.mark.parametrize("b,h,kv,d,bt,mb,lens,v_dim", [
+    pytest.param(2, 8, 2, 16, 4, 6, None, None, id="2-8-2-16-4-6"),
+    pytest.param(3, 4, 4, 32, 8, 4, None, None, id="3-4-4-32-8-4"),
+    pytest.param(1, 8, 1, 64, 16, 3, None, None, id="1-8-1-64-16-3"),
+    pytest.param(4, 32, 2, 128, 16, 64, None, None, id="serving-shape"),
+    pytest.param(4, 8, 2, 16, 4, 6, (0, 9, 0, 24), None, id="empty-lanes"),
+    pytest.param(3, 8, 2, 32, 8, 8, (8, 16, 40), None, id="block-boundary"),
+    pytest.param(3, 4, 2, 16, 4, 6, (24, 24, 1), None, id="full-lanes"),
+    # K=V (latent) tiles: one kv head, V the tile's leading columns
+    pytest.param(4, 16, 1, 640, 16, 64, (0, 1, 100, 1024), 512,
+                 id="latent-serving-shape"),
+    pytest.param(4, 8, 1, 128, 4, 6, (0, 1, 10, 0), 96, id="latent-partial"),
+    pytest.param(3, 8, 1, 128, 4, 6, (24, 24, 24), 96, id="latent-full"),
 ])
-def test_paged_attention_sweep(b, h, kv, d, bt, mb, lens):
+def test_paged_attention_sweep(b, h, kv, d, bt, mb, lens, v_dim):
     """Every slot that no live block maps to holds NaN for the kernel (the
     oracle reads the finite pool): a finite output shows the kernel read
-    only live blocks."""
+    only live blocks. With `v_dim` a slot is one K=V tile (latent
+    attention): lanes of 0 and 1 tokens, a partial chunk, and all-live
+    lanes."""
     n_slots = max(32, b * mb)
     q = _arr((b, h, d))
-    kv_pages = _arr((n_slots, 2, kv, bt, d))
+    kv_pages = _arr((n_slots, 1 if v_dim else 2, kv, bt, d))
     if lens is None:
         lens = RNG.integers(1, bt * mb, b)
     lens = jnp.asarray(lens, jnp.int32)
@@ -125,8 +132,12 @@ def test_paged_attention_sweep(b, h, kv, d, bt, mb, lens):
     dead[np.asarray(tables)[np.asarray(tables) >= 0]] = False
     poisoned = jnp.where(jnp.asarray(dead)[:, None, None, None, None],
                          jnp.nan, kv_pages)
-    got_o, got_t = ops.paged_attention(q, poisoned, tables, lens)
-    want_o, want_t = ref.paged_attention(q, kv_pages, tables, lens)
+    scale = 0.75 * d ** -0.5 if v_dim else None
+    got_o, got_t = ops.paged_attention(q, poisoned, tables, lens,
+                                       scale=scale, v_dim=v_dim)
+    want_o, want_t = ref.paged_attention(q, kv_pages, tables, lens,
+                                         scale=scale, v_dim=v_dim)
+    assert got_o.shape == (b, h, v_dim or d)
     got_o, want_o = np.asarray(got_o), np.asarray(want_o)
     live = np.asarray(lens) > 0
     assert np.isfinite(got_o).all()

@@ -62,6 +62,23 @@ def test_paged_attention_compiles_for_v5e(one_chip, pool_cfg):
              ((b, mb), jnp.int32), ((b,), jnp.int32))
 
 
+def test_latent_paged_attention_compiles_for_v5e(one_chip):
+    """The K=V mode at DeepSeek-V2-Lite's serving shapes: 64 lanes, one
+    576-wide latent a token (640 in the slot), 16 query heads, V its first
+    512 columns, 27 layers of KV in the pool."""
+    kv_cfg = KVCacheConfig(num_layers=27, batch=64, max_blocks=64,
+                           block_tokens=16, num_kv_heads=1, head_dim=576,
+                           v_dim=512)
+    pcfg = kv_cfg.pool_config()
+    pages = (pcfg.n_slots + 1,) + pcfg.row_shape
+    assert pages[1:] == (1, 1, 16, 640)
+    _compile(lambda q, p, t, n: paged_attention.paged_attention_pallas(
+                 q, p, t, n, scale=0.1147, v_dim=512, interpret=False),
+             one_chip, ((64, 1, 16, 640), jnp.bfloat16),
+             (pages, jnp.bfloat16), ((64, 64), jnp.int32),
+             ((64,), jnp.int32))
+
+
 def test_migrate_compiles_for_v5e(one_chip, pool_cfg):
     assert pool_cfg.slot_words == 8192
     pages = (pool_cfg.n_slots + 1,) + pool_cfg.row_shape
