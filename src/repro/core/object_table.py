@@ -126,6 +126,14 @@ def record_access(table: jax.Array, obj_ids: jax.Array,
     n = table.shape[0]
     dst = jnp.where(obj_ids >= 0, obj_ids, n)
     hit = jnp.zeros((n,), jnp.bool_).at[dst].set(True, mode="drop")
+    return mark_access(table, hit, armed)
+
+
+def mark_access(table: jax.Array, hit: jax.Array,
+                armed: bool | jax.Array = False) -> jax.Array:
+    """`record_access` given its hit mask: hit [n] bool, one entry per
+    table word. Elementwise: the access bit of every hit word, and its
+    saturating ATC bump when `armed`."""
     word = table | (ACCESS_MASK << ACCESS_SHIFT)
     bump = hit & jnp.asarray(armed).astype(bool)
     word = jnp.where(bump, with_atc(word, jnp.minimum(atc_of(word) + 1,

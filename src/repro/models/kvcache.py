@@ -16,7 +16,8 @@ pool table right before the kernel — which is what makes migration
 transparent to the serving loop (the paper's pointer-update guarantee).
 
 Everything here is functional and jit-safe; the serving loop in
-runtime/server.py drives (append -> attend -> record -> collect).
+runtime/server.py drives (append -> attend_read per layer -> record once a
+step -> collect).
 """
 from __future__ import annotations
 
@@ -243,8 +244,27 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
            *, seq_lens: Optional[jax.Array] = None,
            use_pallas: Optional[bool] = None,
            scale: Optional[float] = None) -> Tuple[jax.Array, Dict]:
-    """q: [B, H, D] -> (out [B, H, D], state with access recorded); a
-    latent slot (`cfg.v_dim`) gives out [B, H, v_dim]. `scale` multiplies
+    """q: [B, H, D] -> (out, state with this layer's accesses recorded):
+    `attend_read`, then `record` of its one layer. The server's decode
+    step instead reads every layer and records once, after its layer
+    scans (`runtime/server.py` `_model_step`); the pool comes out the
+    same either way (docs/serving.md)."""
+    out, touched = attend_read(cfg, state, layer, q, seq_lens=seq_lens,
+                               use_pallas=use_pallas, scale=scale)
+    step = jnp.zeros((cfg.num_layers, cfg.batch, cfg.max_blocks),
+                     jnp.bool_).at[layer].set(touched)
+    return out, record(cfg, state, step)
+
+
+def attend_read(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
+                *, seq_lens: Optional[jax.Array] = None,
+                use_pallas: Optional[bool] = None,
+                scale: Optional[float] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """q: [B, H, D] -> (out [B, H, D], touched [B, MB] bool); a latent
+    slot (`cfg.v_dim`) gives out [B, H, v_dim]. `touched` marks the
+    layer's block-table entries the read fetched (live, on active
+    lanes); nothing is recorded — `record` takes it. `scale` multiplies
     the scores (default D^-0.5).
     `layer` may be a traced index (the server's decode layer scan).
     `seq_lens` defaults to state["pos"] — correct when the caller has
@@ -254,13 +274,12 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
 
     `use_pallas=None` picks the implementation by backend, mirroring the
     collector's CollectorConfig(use_pallas) split: the Pallas kernel
-    (with its fused access-bit recording) compiles natively on TPU, while
+    (with its fused access bits) compiles natively on TPU, while
     CPU runs the pure-jnp oracle — interpret-mode kernel emulation is
     correctness-only and orders of magnitude too slow for the serving
     hot path (tests/test_kernels.py keeps the two bit-compatible on the
     touched bits and within fp tolerance on the outputs). Both read the
     pool data in place as [n_slots + 1, 2|1, KV, bt, D] pages."""
-    pcfg = cfg.pool_config()
     pool = state["pool"]
     tbl = state["block_tables"][layer]               # [B, MB] logical ids
     live = tbl >= 0
@@ -289,11 +308,7 @@ def attend(cfg: KVCacheConfig, state: Dict, layer: int, q: jax.Array,
     # to a mean over slot 0's payload (a live neighbor's KV) — mask it out
     # rather than leak it
     out = jnp.where(state["active"][:, None, None], out, 0)
-    # the kernel's fused access bits -> object-table access bits
-    touched_ids = jnp.where(touched & live & state["active"][:, None],
-                            tbl, -1).reshape(-1)
-    pool = _record_touched(pcfg, pool, touched_ids)
-    return out, dict(state, pool=pool)
+    return out, touched & live & state["active"][:, None]
 
 
 def _pad_to_slot(cfg: KVCacheConfig, x: jax.Array) -> jax.Array:
@@ -302,34 +317,44 @@ def _pad_to_slot(cfg: KVCacheConfig, x: jax.Array) -> jax.Array:
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
 
 
-def _record_touched(pcfg: pl.PoolConfig, pool: Dict, obj_ids: jax.Array
-                    ) -> Dict:
+@jax.named_scope("record")
+def record(cfg: KVCacheConfig, state: Dict, touched: jax.Array) -> Dict:
     """pool.read's accounting without the data gather (the kernel already
-    did the reads): access bits, ATC when armed, promo/fault counters."""
-    valid = obj_ids >= 0
-    ids = jnp.maximum(obj_ids, 0)
-    words = pool["table"][ids]
-    live = ot.is_live(words) & valid
-    tbl = ot.record_access(pool["table"], jnp.where(live, obj_ids, -1),
-                           armed=pool["armed"])
-    slots = ot.slot_of(words).astype(jnp.int32)
-    slot_ref = pool["slot_ref"].at[
-        jnp.where(live, slots, pcfg.n_slots)].set(True, mode="drop")
-    sbs = slots // pcfg.sb_slots
-    on_host = live & (pool["sb_tier"][sbs] == pl.HOST)
-    fault_mask = jnp.zeros((pcfg.n_sbs,), jnp.bool_).at[
-        jnp.where(on_host, sbs, pcfg.n_sbs)].set(True, mode="drop")
-    n_faults = jnp.sum(fault_mask).astype(jnp.int32)
-    promos = jnp.sum(live & (ot.heap_of(words) == ot.COLD)).astype(jnp.int32)
-    return dict(
-        pool, table=tbl, slot_ref=slot_ref,
-        sb_tier=jnp.where(fault_mask, pl.HBM, pool["sb_tier"]).astype(jnp.int8),
-        sb_evict=jnp.where(fault_mask, pl.NORMAL,
+    did the reads): access bits, ATC when armed, promo/fault counters.
+    touched: [L, B, MB] bool, the `attend_read` masks of a decode step's
+    layers.
+
+    Dense over the object table: a block-table entry is -1 or its own
+    flat index (`obj_id`), so the stacked masks are already the table's
+    hit mask, and each object is hit at most once. Recording a step's
+    layers at once gives the pool that recording each layer after its
+    read gives: nothing between two layers' reads of a step reads or
+    writes the accounting of another layer's objects."""
+    pcfg = cfg.pool_config()
+    pool = state["pool"]
+    table = pool["table"]
+    hit = touched.reshape(-1) & ot.is_live(table)
+    # the hit objects' slots: one scatter of the step's ids into a fresh
+    # mask (a gather of `hit` through `slot_owner` gives the same bits,
+    # at twice the device time on a v5e)
+    slot_hit = jnp.zeros((pcfg.n_slots,), jnp.bool_).at[
+        jnp.where(hit, ot.slot_of(table).astype(jnp.int32), pcfg.n_slots)
+    ].set(True, mode="drop")
+    fault = slot_hit.reshape(pcfg.n_sbs, pcfg.sb_slots).any(1) & \
+        (pool["sb_tier"] == pl.HOST)
+    n_faults = jnp.sum(fault).astype(jnp.int32)
+    promos = jnp.sum(hit & (ot.heap_of(table) == ot.COLD)).astype(jnp.int32)
+    pool = dict(
+        pool, table=ot.mark_access(table, hit, armed=pool["armed"]),
+        slot_ref=pool["slot_ref"] | slot_hit,
+        sb_tier=jnp.where(fault, pl.HBM, pool["sb_tier"]).astype(jnp.int8),
+        sb_evict=jnp.where(fault, pl.NORMAL,
                            pool["sb_evict"]).astype(jnp.int8),
-        win_accesses=pool["win_accesses"] + jnp.sum(live),
+        win_accesses=pool["win_accesses"] + jnp.sum(hit),
         win_promos=pool["win_promos"] + promos,
         win_faults=pool["win_faults"] + n_faults,
         total_faults=pool["total_faults"] + n_faults)
+    return dict(state, pool=pool)
 
 
 # ---------------------------------------------------------------------------

@@ -37,8 +37,8 @@ MEASUREMENT (docs/serving.md): `serve` records itself on
 admission, first token and finish on its `Completion` — and the window
 program returns the post-window KV gauges, so `serve` runs no device work
 of its own for them. The window program's ops carry `jax.named_scope`s
-(qkv, kv_append, attention, ffn, logits, sample, lane_events, collect,
-migrate, backend) that reach the compiled HLO's `op_name` metadata.
+(qkv, kv_append, attention, ffn, record, logits, sample, lane_events,
+collect, migrate, backend) that reach the compiled HLO's `op_name` metadata.
 """
 from __future__ import annotations
 
@@ -190,7 +190,9 @@ class Server:
         Layers run under lax.scan; each layer derives qkv from the CURRENT
         residual stream (exactly once), appends its k/v to the paged pool
         and attends through the object table. Inactive lanes append
-        nothing and attend over zero keys (kvcache's lane mask).
+        nothing and attend over zero keys (kvcache's lane mask). The
+        scans emit each layer's touched blocks; one `kvc.record` after
+        them sets the step's access bits.
 
         A model with `first_k_dense` leading dense layers runs them first,
         from `params["dense_layers"]`, as a scan of their own at KV layer
@@ -215,25 +217,30 @@ class Server:
                 # pos still points AT the appended token (advance_pos
                 # runs after the layer scan) -> the token attends to
                 # itself via pos + 1
-                out, st3 = kvc.attend(cfg, st2, li, q[:, 0],
-                                      seq_lens=st2["pos"] + 1, scale=scale)
-                return out[:, None], st3                # [B,1,H,Dh]
+                out, touched = kvc.attend_read(cfg, st2, li, q[:, 0],
+                                               seq_lens=st2["pos"] + 1,
+                                               scale=scale)
+                return out[:, None], (st2, touched)     # [B,1,H,Dh]
 
-            h, st, counts = T.decode_layer_step(lp, h, mc, positions, attend,
-                                                lanes=st["active"])
+            h, (st, touched), counts = T.decode_layer_step(
+                lp, h, mc, positions, attend, lanes=st["active"])
             if counted and "moe" in lp:
                 st = dict(st,
                           expert_picks=st["expert_picks"] + jnp.sum(counts))
-            return (h, st), None
+            return (h, st), touched
 
+        touched = []
         k = mc.first_k_dense
         if k:
-            (x, state), _ = jax.lax.scan(
+            (x, state), t = jax.lax.scan(
                 layer_body, (x, state), (jnp.arange(k),
                                          params["dense_layers"]))
-        (h, state), _ = jax.lax.scan(
+            touched.append(t)
+        (h, state), t = jax.lax.scan(
             layer_body, (x, state),
             (jnp.arange(k, mc.num_layers), params["layers"]))
+        # the step's access bits, once, over the layer-major table
+        state = kvc.record(cfg, state, jnp.concatenate(touched + [t]))
         state = kvc.advance_pos(state)
         with jax.named_scope("logits"):
             h = L.rms_norm(h, params["final_ln"], mc.norm_eps)

@@ -20,11 +20,12 @@ B, W = 2, 4
 KW = dict(batch=B, max_len=32, block_tokens=4, collect_every=W, window=W)
 PHASES = ("schedule", "inputs", "dispatch", "reports", "tokens", "lanes",
           "log")
-SCOPES = ("qkv", "kv_append", "attention", "ffn", "logits", "sample",
-          "lane_events", "collect", "migrate", "backend")
+SCOPES = ("qkv", "kv_append", "attention", "ffn", "record", "logits",
+          "sample", "lane_events", "collect", "migrate", "backend")
 
 _MODEL = []
 _SERVERS = {}
+_HLO = []
 
 
 def _model():
@@ -196,9 +197,26 @@ def test_profiler_spans_sit_at_one_offset_from_serve_log(tmp_path):
                                                abs=1.0)
 
 
+def _window_hlo():
+    """The compiled serve window's HLO text, compiled once."""
+    if not _HLO:
+        _, params = _model()
+        _HLO.append(_server().lower_serve_window(params).compile()
+                    .as_text())
+    return _HLO[0]
+
+
 def test_window_program_carries_the_named_scopes():
-    _, params = _model()
-    hlo = _server().lower_serve_window(params).compile().as_text()
-    names = re.findall(r'op_name="([^"]*)"', hlo)
+    names = re.findall(r'op_name="([^"]*)"', _window_hlo())
     for scope in SCOPES:
         assert any(f"/{scope}/" in n for n in names), scope
+
+
+def test_layer_loop_scatters_only_to_append():
+    """Access bits are recorded once a step, after the layer scans
+    (`record`): the layers' `attention` scatters nothing but the KV
+    append's slot and block-table writes."""
+    scatters = re.findall(r' scatter\(.*op_name="([^"]*)"', _window_hlo())
+    attention = [n for n in scatters if "/attention/" in n]
+    assert attention, "no scatter of the KV append in the layer loop"
+    assert all("/attention/kv_append/" in n for n in attention), attention
