@@ -170,7 +170,7 @@ def main(smoke: bool = False):
             secs = _best_of(lambda: run_windows(engine, base, trace),
                             repeats)
             # host-side compiled-program launches (same accounting as
-            # bench_serve's Server.dispatches): one run_window call per
+            # Server.dispatches): one run_window call per
             # window, every backend — the stateful ones compile into the
             # SAME single window program (their bstate is scan-carried;
             # a backend that needed a host round-trip would fail at

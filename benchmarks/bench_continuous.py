@@ -7,8 +7,7 @@ finish on EOS/max-tokens and FREE their KV through the pool op stream at
 the next window boundary — the realistic generator of the paper's
 hotness fragmentation (finished requests strand cold blocks interleaved
 with live lanes' hot blocks across superblocks). Emits the
-continuous-batching row into `BENCH_serve.json` (merged with
-bench_serve.py's per-step/windowed/overlapped rows):
+continuous-batching row as `BENCH_serve.json`:
 
   * tokens/sec over the whole churn run,
   * per-window KV-RSS vs live-bytes curves (RSS must TRACK live bytes
@@ -97,7 +96,7 @@ def main(smoke: bool = False):
         "rss_curve": rss, "live_curve": live,
     }}
     out_dir = "bench_out" if smoke else "."
-    emit_json("serve", record, out_dir=out_dir, merge=True)
+    emit_json("serve", record, out_dir=out_dir)
     return record
 
 

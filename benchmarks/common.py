@@ -58,21 +58,13 @@ def emit(name: str, us_per_call: float, derived: str) -> None:
     print(f"{name},{us_per_call:.1f},{derived}")
 
 
-def emit_json(name: str, record: Dict, out_dir: str = ".",
-              merge: bool = False) -> str:
+def emit_json(name: str, record: Dict, out_dir: str = ".") -> str:
     """Write one benchmark record to `BENCH_<name>.json` (the repo's perf
     trajectory artifacts) and echo it to stdout; creates `out_dir` if
     missing (smoke runs point at the gitignored `bench_out/` scratch
-    dir). `merge=True` shallow-merges into an existing artifact instead
-    of replacing it — how several benches share one file (bench_serve +
-    bench_continuous both feed BENCH_serve.json). Returns the path."""
+    dir). Returns the path."""
     os.makedirs(out_dir, exist_ok=True)
     path = os.path.join(out_dir, f"BENCH_{name}.json")
-    if merge and os.path.exists(path):
-        with open(path) as fh:
-            merged = json.load(fh)
-        merged.update(record)
-        record = merged
     with open(path, "w") as fh:
         json.dump(record, fh, indent=2, sort_keys=True)
         fh.write("\n")

@@ -18,8 +18,8 @@ from benchmarks import (bench_embedding, bench_kernels,
                         fig3_unreclaimable, fig6_crestdb, fig7_backends,
                         roofline_report, table1_structures)
 
-# serving benches live outside this CSV aggregator: bench_serve.py and
-# bench_continuous.py emit the BENCH_serve.json perf-trajectory artifact
+# the serving bench lives outside this CSV aggregator:
+# bench_continuous.py emits the BENCH_serve.json perf-trajectory artifact
 SUITES = [
     ("fig2_page_utilization", fig2_page_utilization.main),
     ("fig3_unreclaimable", fig3_unreclaimable.main),

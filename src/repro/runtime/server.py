@@ -1,24 +1,24 @@
 """Batched decode server with the HADES-managed paged KV cache.
 
-The serving hot path runs as SCANNED DECODE WINDOWS: `decode_window`
-executes W decode steps — embed, per-layer (qkv -> paged append -> attend
-through the object table -> ffn), logits, sample, and the window-closing
-collect+MIAD+backend — as ONE jitted `lax.scan`, built on the same
-`engine.window_program` machinery (and therefore the same op-clock /
-collect-cadence contract) as `Engine.run_window`. `decode_step` is the
-per-step reference path: the identical transition, one dispatch per
-token, bit-identical to the windowed path (tests/test_server_window.py).
+The server compiles ONE decode program, the serving window `_win_serve`:
+window-entry lane events, then W decode steps — embed, per-layer (qkv ->
+paged append -> attend through the object table -> ffn), logits, sample —
+with the fused collect+MIAD+backend closing every `collect_every` steps,
+as ONE jitted `lax.scan`, built on `engine.window_program`'s cond-free
+aligned shape (the op-clock / collect-cadence contract of
+`Engine.run_window`). `serve`, `generate` and `decode_window` all run it.
+`decode_step` is the tests' per-step reference, not a serving path: the
+identical transition, one dispatch per token, bit-identical to the window
+(tests/test_server_window.py).
 
 Per layer the residual stream `h` advances BEFORE the next layer's k/v is
 derived (each layer's k/v is a function of the previous layers' output —
 the old two-phase loop computed every layer's k/v from the embedding and
 wrote corrupted bytes into the paged pool).
 
-`overlap_collect=True` is the double-buffered serving loop the ATC/arm
-epoch protocol exists for: windows arm one step before closing (objects
-dereferenced by an in-flight step carry ATC > 0 and are never migrated),
-and `generate` defers each window's report sync until the NEXT window's
-dispatch has been issued — collection resolves while decode runs.
+The window never arms the ATC epoch: its collects run inside the same
+program, after the steps before them have retired, so no step is in
+flight for the arm to protect (docs/engine.md).
 
 CONTINUOUS BATCHING (`Server.serve`, docs/serving.md): lanes carry a
 lifecycle — admit -> decode -> finish on EOS/max-tokens -> free -> refill
@@ -81,10 +81,6 @@ class ServerConfig:
     # collect_every): W steps run as ONE dispatch, window protocol
     # included
     window: int = 0
-    # double-buffered serving: windows arm the ATC epoch one step before
-    # closing, and `generate`/`serve` sync window N's report only after
-    # window N+1's dispatch is in flight
-    overlap_collect: bool = False
     # route the collector through the Pallas kernels (interpret on CPU)
     use_pallas: bool = False
     # in-scan sampling defaults for `generate(greedy=False)`:
@@ -250,8 +246,6 @@ class Server:
         return state, logits
 
     def _build_programs(self):
-        every = int(self.cfg.collect_every)
-        overlap = bool(self.cfg.overlap_collect)
         cab = functools.partial(kvc.collect_and_backend, self.kv_cfg,
                                 self.col_cfg, self.backend)
 
@@ -284,9 +278,6 @@ class Server:
             kvstate, report = cab(carry["kv"])
             return dict(carry, kv=kvstate), report
 
-        def win_arm(carry):
-            return dict(carry, kv=kvc.arm(carry["kv"]))
-
         @jax.named_scope("lane_events")
         def win_events(carry, ev):
             """Window-entry lane events, fused into the window dispatch:
@@ -302,27 +293,18 @@ class Server:
                         topk=jnp.where(ev["admit"], ev["topk"],
                                        carry["topk"]))
 
-        def _programs(params, do_sample, pre_fn=None):
-            return eng.window_program(
-                functools.partial(win_step, params, do_sample),
-                win_collect, win_arm,
-                every=every, overlap=overlap, pre_fn=pre_fn)
-
-        def aligned(params, carry, toks, do_sample):
-            return _programs(params, do_sample)[1](carry, toks)
-
-        def generic(params, carry, toks, step0, do_sample):
-            return _programs(params, do_sample)[0](carry, toks, step0)
-
         def serve_aligned(params, carry, toks, events, do_sample):
-            """The continuous-batching window: lane events applied at
-            the window entry, then W steps + collect — one dispatch.
-            Returns (carry, outs, reports, gauges): `gauges` are the
-            post-window KV gauges, computed as `kv_rss_bytes` and
-            `kv_live_bytes` compute them (`rss_bytes`, `live_blocks`)."""
-            carry, outs, reports = _programs(
-                params, do_sample, pre_fn=win_events)[1](carry, toks,
-                                                         events)
+            """The serving window: lane events applied at the window
+            entry, then W steps + a collect every `collect_every` — one
+            dispatch. It never arms (no arm_fn). Returns (carry, outs,
+            reports, gauges): `gauges` are the post-window KV gauges,
+            computed as `kv_rss_bytes` and `kv_live_bytes` compute them
+            (`rss_bytes`, `live_blocks`)."""
+            run = eng.window_program(
+                functools.partial(win_step, params, do_sample),
+                win_collect, None, every=self.cfg.collect_every,
+                pre_fn=win_events)[1]
+            carry, outs, reports = run(carry, toks, events)
             kv = carry["kv"]
             gauges = {"rss_bytes": pl.rss_bytes(self.kv_cfg.pool_config(),
                                                 kv["pool"]),
@@ -331,13 +313,10 @@ class Server:
                 gauges["expert_picks"] = kv["expert_picks"]
             return carry, outs, reports, gauges
 
-        def step_apply(params, carry, tok, do_arm, do_collect,
-                       do_sample):
-            """decode_step's program: the identical transition, collect
-            and arm fused in statically (the host knows the clock)."""
+        def step_apply(params, carry, tok, do_collect, do_sample):
+            """decode_step's program: the identical transition, the
+            collect fused in statically (the host knows the clock)."""
             carry, out = win_step(params, do_sample, carry, tok)
-            if do_arm:
-                carry = win_arm(carry)
             if do_collect:
                 carry, report = win_collect(carry)
             else:
@@ -351,15 +330,11 @@ class Server:
         # a carry after passing it in (all carried leaves are reassigned
         # from the returned carry; tests/test_donation.py). `do_sample`
         # is static: the greedy variant compiles without the sampler.
-        self._win_aligned = jax.jit(aligned, donate_argnums=(1,),
-                                    static_argnames=("do_sample",))
-        self._win_generic = jax.jit(generic, donate_argnums=(1,),
-                                    static_argnames=("do_sample",))
         self._win_serve = jax.jit(serve_aligned, donate_argnums=(1,),
                                   static_argnames=("do_sample",))
         self._step_apply = jax.jit(
             step_apply,
-            static_argnames=("do_arm", "do_collect", "do_sample"),
+            static_argnames=("do_collect", "do_sample"),
             donate_argnums=(1,))
 
     # -- the decode carry (donated per dispatch, mirrors reassigned) ----------
@@ -375,19 +350,15 @@ class Server:
     # -- one decode step across the batch -------------------------------------
     def decode_step(self, params, tokens: jax.Array
                     ) -> Tuple[jax.Array, None]:
-        """tokens: [B] -> (logits [B, V], None). ONE dispatch: the model
-        step plus — statically, from the host-side window clock — the ATC
-        arm and the fused collect+MIAD+backend. The per-step reference
-        for `decode_window` (bit-identical transitions)."""
-        nxt = self._steps + 1
-        every = self.cfg.collect_every
-        do_arm = bool(self.cfg.overlap_collect) and \
-            nxt % every == every - 1
-        do_collect = nxt % every == 0
+        """tokens: [B] -> (logits [B, V], None). The tests' per-step
+        reference for the serving window, not a serving path: ONE
+        dispatch a token, the model step plus — statically, from the
+        host-side clock — the fused collect+MIAD+backend at a collect
+        boundary. Bit-identical to `decode_window` step for step."""
+        do_collect = (self._steps + 1) % self.cfg.collect_every == 0
         carry, out, report = self._step_apply(
             params, self._carry(), jnp.asarray(tokens, jnp.int32),
-            do_arm=do_arm, do_collect=do_collect,
-            do_sample=self._sample_in_scan)
+            do_collect=do_collect, do_sample=self._sample_in_scan)
         self._uncarry(carry)
         self._steps += 1
         self.dispatches += 1
@@ -398,17 +369,17 @@ class Server:
     # -- scanned decode windows ------------------------------------------------
     def decode_window(self, params, tokens: jax.Array,
                       w: Optional[int] = None):
-        """Run a whole decode window as ONE dispatch.
+        """Run T decode steps as ONE dispatch of the serving window
+        `_win_serve`, with no lane events.
 
         tokens: [B, T] int32 — entries >= 0 are teacher-forced, entries
         < 0 self-feed the previously sampled token; or [B] (a seed token
         per sequence) with `w` given, running `w` steps (seed then
         self-feed). Every step embeds, runs all layers (paged append +
-        attend), computes logits and samples; window-closing steps run
-        the fused collect+MIAD+backend in the same program (and, with
-        overlap_collect, arm the ATC epoch one step earlier). Uses the
-        cond-free window-aligned program when T and the op clock align
-        with collect_every, the generic cond-gated one otherwise.
+        attend), computes logits and samples; each `collect_every`-th
+        step closes with the fused collect+MIAD+backend in the same
+        program. T must be a positive multiple of collect_every and the
+        clock must stand at a collect boundary, else ValueError.
 
         Returns (logits [B, T, V], sampled [B, T], per-step report
         pytree — feed to engine.window_reports to extract the collects)."""
@@ -421,14 +392,15 @@ class Server:
         toks = toks.T                                   # scan axis first
         t = int(toks.shape[0])
         every = self.cfg.collect_every
-        carry = self._carry()
-        if t > 0 and t % every == 0 and self._steps % every == 0:
-            carry, outs, reports = self._win_aligned(
-                params, carry, toks, do_sample=self._sample_in_scan)
-        else:
-            carry, outs, reports = self._win_generic(
-                params, carry, toks, self._steps,
-                do_sample=self._sample_in_scan)
+        if t <= 0 or t % every or self._steps % every:
+            raise ValueError(
+                f"decode_window runs whole collect windows: T={t} must be "
+                f"a positive multiple of collect_every={every}, and the "
+                f"clock ({self._steps} steps) must stand at a multiple of "
+                "it")
+        carry, outs, reports, _ = self._win_serve(
+            params, self._carry(), toks, self._idle_events(t),
+            do_sample=self._sample_in_scan)
         self._uncarry(carry)
         self._steps += t
         self.dispatches += 1
@@ -439,8 +411,11 @@ class Server:
                  *, greedy: bool = True, key=None) -> jax.Array:
         """prompts: [B, P], teacher-forced through the same scanned decode
         path (prefill exercises HADES on the prefix blocks), then
-        `max_new` tokens — window-by-window (W = cfg.window or
-        collect_every), O(tokens / W) dispatches.
+        `max_new` tokens — in whole serving windows (W = cfg.window or
+        collect_every; `decode_window`), O(tokens / W) dispatches. The
+        forced-token matrix is padded with self-feed steps up to a whole
+        number of windows, so the clock then stands at a window boundary;
+        appends past a lane's capacity (cfg.max_len) are dropped.
 
         `greedy=True` decodes argmax (bit-identical to the pre-sampler
         path; `key` is optional and only seeds the carried PRNG).
@@ -448,12 +423,7 @@ class Server:
         every lane and REQUIRES `key` — sampling without randomness used
         to fall back to greedy silently; now it refuses. (A
         cfg.temperature <= 0 still means argmax — that is lane
-        configuration, not a fallback.)
-
-        With overlap_collect the loop is double-buffered: window N's
-        report sync (the only host<->device round trip) happens only
-        after window N+1's dispatch is in flight, so collection resolves
-        while the next window decodes."""
+        configuration, not a fallback.)"""
         if not greedy and key is None:
             raise ValueError(
                 "generate(greedy=False) samples inside the decode scan "
@@ -470,26 +440,18 @@ class Server:
             self._topk = jnp.full((b,), self.cfg.top_k, jnp.int32)
         if max_new <= 0:
             return jnp.zeros((b, 0), jnp.int32)
-        total = p + max_new - 1
+        w = self.cfg.window or self.cfg.collect_every
+        steps = -(-(p + max_new - 1) // w) * w          # whole windows
         forced = jnp.concatenate(
             [jnp.asarray(prompts, jnp.int32),
-             jnp.full((b, max_new - 1), -1, jnp.int32)], axis=1)
-        w = self.cfg.window or self.cfg.collect_every
+             jnp.full((b, steps - p), -1, jnp.int32)], axis=1)
         sampled = []
-        pending = None
-        for lo in range(0, total, w):
+        for lo in range(0, steps, w):
             _, toks, rep = self.decode_window(params, forced[:, lo:lo + w])
             sampled.append(toks)
-            if self.cfg.overlap_collect:
-                if pending is not None:
-                    self.reports.extend(eng.window_reports(pending))
-                pending = rep
-            else:
-                self.reports.extend(eng.window_reports(rep))
-        if pending is not None:
-            self.reports.extend(eng.window_reports(pending))
-        out = jnp.concatenate(sampled, axis=1)          # [B, total]
-        return out[:, p - 1:]
+            self.reports.extend(eng.window_reports(rep))
+        out = jnp.concatenate(sampled, axis=1)          # [B, steps]
+        return out[:, p - 1:p - 1 + max_new]
 
     # -- continuous batching ---------------------------------------------------
     def serve(self, params, requests: Sequence[Request], *, key=None,
@@ -505,8 +467,7 @@ class Server:
         pool op stream INSIDE that dispatch, before the first step. The
         sampled tokens sync back at the window boundary (the host must
         inspect them to schedule lanes — the sync a continuous batcher
-        cannot avoid, paid once per W tokens); with overlap_collect the
-        collect REPORT sync is still deferred one window.
+        cannot avoid, paid once per W tokens).
 
         A lane finishes on EOS, on its request's max_new, or at the
         lane capacity (cfg.max_len). Prompts must fit a lane
@@ -567,7 +528,6 @@ class Server:
             # generous safety valve: sequential worst case + drain
             max_windows = 2 + sum(
                 -(-(len(r.prompt) + r.max_new) // w) + 1 for r in requests)
-        overlap = self.cfg.overlap_collect
         slot_bytes = self.kv_cfg.pool_config().slot_bytes
         mc = self.model.cfg
         # the drop-free expert layer reads every held expert, each MoE
@@ -576,7 +536,6 @@ class Server:
             mc.num_layers - mc.first_k_dense) if mc.held_experts else None
         picks_seen = 0
         window_idx = 0
-        pending = None
         while True:
             host_ms: Dict[str, float] = {}
             with jax.profiler.StepTraceAnnotation("serve.window",
@@ -638,24 +597,14 @@ class Server:
                     lanes[i].t_admitted = t_dispatch
 
                 # -- window-boundary syncs: reports, then tokens ---------
-                # (t_ready: the host first holds this window's outputs —
-                # at the report sync, or with overlap_collect, whose
-                # report sync reads the previous window, at the tokens)
+                # (t_ready: the host first holds this window's outputs)
                 with _phase(host_ms, "reports"):
-                    if overlap:
-                        if pending is not None:
-                            self.reports.extend(
-                                eng.window_reports(pending))
-                        pending = rep
-                    else:
-                        jax.block_until_ready(rep)
-                        t_ready = time.perf_counter()
-                        self.reports.extend(eng.window_reports(rep))
+                    jax.block_until_ready(rep)
+                    t_ready = time.perf_counter()
+                    self.reports.extend(eng.window_reports(rep))
                 with _phase(host_ms, "tokens"):
                     tok, gauges = jax.device_get((outs["tok"], gauges))
                     t_tokens = time.perf_counter()
-                if overlap:
-                    t_ready = t_tokens
 
                 # -- schedule lanes off the samples ----------------------
                 useful = 0      # lane-steps that fed a prompt or kept token
@@ -707,8 +656,6 @@ class Server:
                             expert_picks=picks - picks_seen,
                             experts_read=experts_read)
                         picks_seen = picks
-        if pending is not None:
-            self.reports.extend(eng.window_reports(pending))
         assert all(r is not None for r in results)
         # the pool is drained; hand the server back in the fixed-batch
         # contract (all lanes live at pos 0) so a later generate /
@@ -730,19 +677,23 @@ class Server:
             "topk": jnp.zeros((w, b), jnp.int32).at[0].set(topk),
         }
 
+    def _idle_events(self, w: int) -> Dict:
+        """A window's lane events that change nothing: no lane freed or
+        admitted."""
+        b = self.cfg.batch
+        no = np.zeros((b,), bool)
+        return self._window_events(w, no, no, np.zeros((b,), np.float32),
+                                   np.zeros((b,), np.int32))
+
     def lower_serve_window(self, params, *, do_sample: bool = False):
         """`serve`'s window program (lane events + W steps + collect, the
         one dispatch per window) lowered at this server's geometry, for
         compile checks: its HLO text (`.as_text()`), and `.compile()`'s
         memory analysis. Nothing runs and the carry is not consumed."""
         w = self.cfg.window or self.cfg.collect_every
-        b = self.cfg.batch
-        no = np.zeros((b,), bool)
-        events = self._window_events(w, no, no, np.zeros((b,), np.float32),
-                                     np.zeros((b,), np.int32))
-        return self._win_serve.lower(params, self._carry(),
-                                     jnp.zeros((w, b), jnp.int32), events,
-                                     do_sample=do_sample)
+        return self._win_serve.lower(
+            params, self._carry(), jnp.zeros((w, self.cfg.batch), jnp.int32),
+            self._idle_events(w), do_sample=do_sample)
 
     def reset(self, active: bool = True) -> None:
         """Fresh serving state (empty pool, zeroed clock/reports/sampling

@@ -98,9 +98,10 @@ def test_serve_steps_streams_with_donation():
 
 
 def test_server_decode_paths_never_reuse_donated_carry():
-    """The server's three programs (step / aligned window / generic
-    window) all donate the decode carry; generate streams across them
-    and the previous window's pool buffers are actually released."""
+    """The server's two programs (the per-step reference / the serving
+    window) both donate the decode carry; generate streams across
+    windows and the previous window's pool buffers are actually
+    released."""
     from repro.models.model import build
     from repro.runtime.server import Server, ServerConfig
 
@@ -120,7 +121,11 @@ def test_server_decode_paths_never_reuse_donated_carry():
     srv.decode_step(params, toks)             # donates the held carry
     assert pool_before.is_deleted(), \
         "decode did not donate the previous pool buffer"
-    # generic (non-aligned) window after the step still works
-    logits, sampled, _ = srv.decode_window(params, toks[:, None])
+    for _ in range(3):                        # back to a collect boundary
+        srv.decode_step(params, toks)
+    # a window after the steps still works, and donates the carry too
+    pool_before = srv.state["pool"]["data"]
+    logits, sampled, _ = srv.decode_window(params, toks, w=4)
+    assert pool_before.is_deleted()
     assert bool(jnp.all(jnp.isfinite(logits)))
     assert srv.kv_rss_bytes() > 0

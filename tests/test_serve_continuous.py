@@ -117,23 +117,24 @@ def test_serve_one_dispatch_per_window_and_drains_pool():
     assert max(rss) > rss[-1] == 0.0
 
 
-@pytest.mark.parametrize("overlap", [False, True])
-def test_refilled_lane_bit_identical_to_fresh_server(overlap):
+@pytest.mark.parametrize("use_pallas", [False, True])
+def test_refilled_lane_bit_identical_to_fresh_server(use_pallas):
     """A refilled lane (slots reused from a freed predecessor, pool
     shared with a live neighbor) decodes bit-identically to a fresh
     server decoding the same prompt — migration-transparent pointer
-    dereferences + per-lane pos make lane history invisible."""
+    dereferences + per-lane pos make lane history invisible — under
+    the jnp collector and the Pallas one."""
     m, params = _model()
     prompt_c = _prompt(3, 7)
     # lane churn: rid0 finishes fast -> its lane refills with rid2
     reqs = [Request(prompt=_prompt(2, 5), max_new=2),
             Request(prompt=_prompt(4, 6), max_new=14),
             Request(prompt=prompt_c, max_new=6)]
-    srv = Server(m, ServerConfig(overlap_collect=overlap, **KW))
+    srv = Server(m, ServerConfig(use_pallas=use_pallas, **KW))
     res = srv.serve(params, reqs)
     assert res[2].windows[0] > 0, "rid2 was not a refill"
 
-    fresh = Server(m, ServerConfig(overlap_collect=overlap, **KW))
+    fresh = Server(m, ServerConfig(use_pallas=use_pallas, **KW))
     ref = fresh.serve(params, [Request(prompt=prompt_c, max_new=6)])
     assert res[2].tokens == ref[0].tokens
     assert res[2].finish_reason == ref[0].finish_reason
@@ -221,9 +222,10 @@ def test_sampled_lanes_top_k_support():
     # replay the sampled stream teacher-forced through a fresh server to
     # recover each step's logits, then check membership in its top-k
     replay = Server(m, ServerConfig(**KW))
-    forced = jnp.concatenate([prompts, out[:, :-1]], axis=1)
+    # (the last sampled token, fed too, fills the second window)
+    forced = jnp.concatenate([prompts, out], axis=1)
     logits, _, _ = replay.decode_window(params, forced)
-    steps = logits[:, prompts.shape[1] - 1:]            # [B, 6, V]
+    steps = logits[:, prompts.shape[1] - 1:-1]          # [B, 6, V]
     topk_ids = jnp.argsort(steps, axis=-1)[..., -k:]
     for b in range(B):
         for t in range(out.shape[1]):

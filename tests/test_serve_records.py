@@ -35,15 +35,14 @@ def _model():
     return _MODEL[0]
 
 
-def _server(backend="proactive", overlap=False):
-    """One compiled server per (backend, overlap), shared by the tests.
-    The reactive backend at its default HBM target of 0 bytes demotes
-    every resident superblock at each collect."""
-    if (backend, overlap) not in _SERVERS:
+def _server(backend="proactive"):
+    """One compiled server per backend, shared by the tests. The
+    reactive backend at its default HBM target of 0 bytes demotes every
+    resident superblock at each collect."""
+    if backend not in _SERVERS:
         m, _ = _model()
-        _SERVERS[backend, overlap] = Server(m, ServerConfig(
-            backend=backend, overlap_collect=overlap, **KW))
-    return _SERVERS[backend, overlap]
+        _SERVERS[backend] = Server(m, ServerConfig(backend=backend, **KW))
+    return _SERVERS[backend]
 
 
 def _requests():
@@ -60,9 +59,8 @@ def _serve(srv):
     return srv.serve(params, _requests())
 
 
-@pytest.mark.parametrize("overlap", [False, True])
-def test_window_stamps_are_ordered(overlap):
-    srv = _server(overlap=overlap)
+def test_window_stamps_are_ordered():
+    srv = _server()
     _serve(srv)
     log = srv.serve_log
     assert len(log) > 3
@@ -75,9 +73,8 @@ def test_window_stamps_are_ordered(overlap):
         assert 0 <= e["useful_lane_steps"] <= e["lane_steps"]
 
 
-@pytest.mark.parametrize("overlap", [False, True])
-def test_completion_stamps_agree_with_windows(overlap):
-    srv = _server(overlap=overlap)
+def test_completion_stamps_agree_with_windows():
+    srv = _server()
     done = _serve(srv)
     log = srv.serve_log
     for c, r in zip(done, _requests()):
@@ -112,10 +109,9 @@ def test_host_phases_fit_between_dispatches():
         assert after + before <= 1e3 * (b["t_dispatch"] - a["t_dispatch"])
 
 
-@pytest.mark.parametrize("backend,overlap", [
-    ("proactive", False), ("proactive", True), ("reactive", True)])
-def test_in_window_gauges_equal_the_methods(backend, overlap):
-    srv = _server(backend, overlap)
+@pytest.mark.parametrize("backend", ["proactive", "reactive", "mglru"])
+def test_in_window_gauges_equal_the_methods(backend):
+    srv = _server(backend)
     after = []
     uncarry = srv._uncarry
 

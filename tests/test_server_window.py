@@ -1,11 +1,11 @@
-"""Scanned decode windows: `Server.decode_window` (W model steps + the
-window-closing collect+backend as ONE jitted scan) must be BIT-identical
-to W sequential `Server.decode_step`s — logits, pool bytes (scratch row
-included), block tables, and collect reports — for both collector paths
-(jnp oracle and Pallas interpret), both window shapes (aligned and
-generic), and with the overlap_collect arm protocol on. Plus the
-armed-window ATC semantics the double-buffered loop relies on, and the
-`generate` e2e ride."""
+"""Scanned decode windows: `Server.decode_window` (the serving window
+`_win_serve` with no lane events: W model steps + the window-closing
+collect+backend as ONE jitted scan) must be BIT-identical to W sequential
+`Server.decode_step`s, the per-step reference — logits, pool bytes
+(scratch row included), block tables, backend state and collect reports —
+for both collector paths (jnp oracle and Pallas interpret) and a
+stateless and a stateful backend. Plus the window's alignment contract,
+the clock across calls, and the `generate` e2e ride."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -43,19 +43,18 @@ def _assert_state_equal(a, b):
 
 
 @pytest.mark.parametrize("use_pallas", [False, True])
-@pytest.mark.parametrize("overlap", [False, True])
+@pytest.mark.parametrize("backend", ["proactive", "mglru"])
 @pytest.mark.parametrize("arch,t", [("chatglm3-6b", 2 * EVERY),
-                                    ("olmoe-1b-7b", EVERY + 2)])
-def test_decode_window_matches_per_step(use_pallas, overlap, arch, t):
+                                    ("olmoe-1b-7b", EVERY)])
+def test_decode_window_matches_per_step(use_pallas, backend, arch, t):
     """One window dispatch == t per-step dispatches, bit for bit: logits,
     sampled tokens, pool state (data incl. the scratch row, table, tiers,
-    counters), and reports. t covers the cond-free window-aligned shape
-    (2 windows) and the generic cond-gated shape (t % every != 0); the
-    MoE arch covers the expert path inside the layer scan."""
+    counters), the backend's carried state (mglru's generations), and
+    reports. t covers one and two collects a window; the MoE arch covers
+    the expert path inside the layer scan."""
     m, params = _model(arch)
     toks = _toks(m, t)
-    cfg = ServerConfig(use_pallas=use_pallas, overlap_collect=overlap,
-                       **KW)
+    cfg = ServerConfig(use_pallas=use_pallas, backend=backend, **KW)
     srv_a, srv_b = Server(m, cfg), Server(m, cfg)
 
     logits_a = jnp.stack(
@@ -77,58 +76,54 @@ def test_decode_window_matches_per_step(use_pallas, overlap, arch, t):
 
 
 def test_decode_window_resumes_clock_across_calls():
-    """Successive windows share the op clock: two decode_window calls of
-    every//2 steps each close exactly one collect between them, same as
-    the per-step path."""
-    m, params = _model()
-    toks = _toks(m, EVERY)
-    srv = Server(m, ServerConfig(**KW))
-    _, _, r1 = srv.decode_window(params, toks[:, :EVERY // 2])
-    _, _, r2 = srv.decode_window(params, toks[:, EVERY // 2:])
-    assert len(eng.window_reports(r1)) == 0
-    assert len(eng.window_reports(r2)) == 1
-
-
-def test_overlap_collect_armed_window_atc_semantics():
-    """The epoch protocol under overlap: the window arms one step before
-    closing, so every object the closing step dereferences carries
-    ATC > 0 and is vetoed (skipped_atc > 0, nothing migrates, the armed
-    flag is consumed by the collect). The synchronous window migrates the
-    same objects freely."""
+    """Successive windows share the op clock: two one-window calls close
+    one collect each and end where one two-window call ends."""
     m, params = _model()
     toks = _toks(m, 2 * EVERY)
-
-    srv_sync = Server(m, ServerConfig(**KW))
-    _, _, rep_s = srv_sync.decode_window(params, toks)
-    rep_s = eng.window_reports(rep_s)
-
-    srv_ovl = Server(m, ServerConfig(overlap_collect=True, **KW))
-    _, _, rep_o = srv_ovl.decode_window(params, toks)
-    rep_o = eng.window_reports(rep_o)
-
-    # decode touches every live block each step, so with overlap all
-    # would-be movers were dereferenced inside the armed epoch
-    assert rep_o[0]["skipped_atc"] > 0
-    assert rep_o[0]["moved_to_hot"] == 0
-    assert rep_s[0]["skipped_atc"] == 0
-    assert rep_s[0]["moved_to_hot"] > 0
-    # the collect consumed the armed flag
-    assert not bool(srv_ovl.state["pool"]["armed"])
+    srv_a, srv_b = Server(m, ServerConfig(**KW)), Server(m, ServerConfig(**KW))
+    la1, _, r1 = srv_a.decode_window(params, toks[:, :EVERY])
+    la2, _, r2 = srv_a.decode_window(params, toks[:, EVERY:])
+    lb, _, rb = srv_b.decode_window(params, toks)
+    assert len(eng.window_reports(r1)) == len(eng.window_reports(r2)) == 1
+    assert eng.window_reports(r1) + eng.window_reports(r2) == \
+        eng.window_reports(rb)
+    assert np.array_equal(np.asarray(jnp.concatenate([la1, la2], axis=1)),
+                          np.asarray(lb))
+    _assert_state_equal(srv_a.state, srv_b.state)
+    assert srv_a._steps == srv_b._steps == 2 * EVERY
 
 
-@pytest.mark.parametrize("overlap", [False, True])
-def test_generate_rides_windows(overlap):
-    """`generate` == the manual per-step greedy loop, at O(tokens/W)
-    dispatches; with overlap_collect the double-buffered report sync
-    still surfaces every closed window exactly once, in order."""
+def test_decode_window_rejects_unaligned_windows():
+    """The window runs whole collect windows from a collect boundary: a
+    length that is not a multiple of collect_every, and a clock that
+    `decode_step` left between boundaries, each raise."""
+    m, params = _model()
+    toks = _toks(m, EVERY + 2)
+    srv = Server(m, ServerConfig(**KW))
+    with pytest.raises(ValueError, match=f"T={EVERY + 2}.*"
+                       f"collect_every={EVERY}"):
+        srv.decode_window(params, toks)
+    assert (srv._steps, srv.dispatches) == (0, 0)
+    srv.decode_step(params, toks[:, 0])
+    with pytest.raises(ValueError, match=r"clock \(1 steps\)"):
+        srv.decode_window(params, toks[:, 1:1 + EVERY])
+    assert (srv._steps, srv.dispatches) == (1, 1)
+
+
+@pytest.mark.parametrize("max_new", [10, 8],
+                         ids=["whole-windows", "ragged-tail"])
+def test_generate_rides_windows(max_new):
+    """`generate` == the manual per-step greedy loop, at ceil(steps / W)
+    dispatches. 3 + 10 - 1 = 12 steps are whole windows of 4 and close
+    the per-step loop's 3 collects; 3 + 8 - 1 = 10 steps pad the last
+    window with self-feed steps, whose tokens are dropped."""
     m, params = _model()
     prompts = _toks(m, 3, seed=1)
-    max_new = 10                      # total steps 12 -> 3 collects
 
-    srv_w = Server(m, ServerConfig(overlap_collect=overlap, **KW))
+    srv_w = Server(m, ServerConfig(**KW))
     out_w = srv_w.generate(params, prompts, max_new=max_new)
 
-    srv_s = Server(m, ServerConfig(overlap_collect=overlap, **KW))
+    srv_s = Server(m, ServerConfig(**KW))
     tok = None
     outs = []
     for t in range(prompts.shape[1]):
@@ -143,9 +138,11 @@ def test_generate_rides_windows(overlap):
 
     assert out_w.shape == (B, max_new)
     assert np.array_equal(np.asarray(out_w), np.asarray(out_s))
-    assert srv_w.reports == srv_s.reports
     total = prompts.shape[1] + max_new - 1
+    if total % EVERY == 0:
+        assert srv_w.reports == srv_s.reports
     assert srv_w.dispatches == -(-total // EVERY)
+    assert srv_w._steps == srv_w.dispatches * EVERY
     assert srv_s.dispatches == total
 
 
