@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -197,12 +197,19 @@ def heap_of_slot(cfg: PoolConfig, slot: jax.Array) -> jax.Array:
 
 
 def apply_op(cfg: PoolConfig, state: Dict, op, obj_ids: jax.Array,
-             values: jax.Array) -> Tuple[Dict, jax.Array]:
+             values: Optional[jax.Array] = None
+             ) -> Tuple[Dict, jax.Array]:
     """All four pool ops as ONE op-code-parameterized transition.
-    `op` may be a TRACED scalar (the engine's batched traces) or a python
-    constant (the per-op wrappers below — XLA folds the masks and
-    recovers each op's minimal program). Returns (state, read_vals [k,W];
-    zeros for non-read ops and dead/padding lanes).
+    `op` may be a TRACED scalar (the engine's batched traces, which carry
+    `values` [k,W] for every op) or a python constant (the per-op
+    wrappers below). Returns (state, read_vals [k,W]; zeros for non-read
+    ops and dead/padding lanes).
+
+    `values=None` means the op writes no payload (read, free): no rows
+    are built and `data` is not scattered at all. Folding the masks of a
+    constant op does NOT remove a payload scatter — with an all-false
+    mask XLA still writes every one of the k zero rows onto the scratch
+    row — so an op without a payload must pass none.
 
     Why not `lax.switch` over four per-op branches: branches that update
     different subsets of the state pytree break XLA's in-place aliasing
@@ -257,14 +264,17 @@ def apply_op(cfg: PoolConfig, state: Dict, op, obj_ids: jax.Array,
     a_slot = jnp.where(ok_new, new_slot, slots)
 
     # --- data: one scatter serves write + alloc (dead/padding lanes
-    # route to the scratch row and must write ZEROS — its invariant) ---
-    d_mask = (is_write & live) | a_do
-    d_slot = jnp.where(is_alloc, a_slot, slots)
+    # route to the scratch row and must write ZEROS — its invariant);
+    # an op without a payload leaves `data` as it is ---
     k = obj_ids.shape[0]
-    rows = values.astype(state["data"].dtype).reshape((k,) + cfg.row_shape)
-    row_mask = d_mask.reshape((k,) + (1,) * len(cfg.row_shape))
-    data = state["data"].at[jnp.where(d_mask, d_slot, cfg.n_slots)].set(
-        jnp.where(row_mask, rows, 0), mode="drop")
+    data = state["data"]
+    if values is not None:
+        d_mask = (is_write & live) | a_do
+        d_slot = jnp.where(is_alloc, a_slot, slots)
+        rows = values.astype(data.dtype).reshape((k,) + cfg.row_shape)
+        row_mask = d_mask.reshape((k,) + (1,) * len(cfg.row_shape))
+        data = data.at[jnp.where(d_mask, d_slot, cfg.n_slots)].set(
+            jnp.where(row_mask, rows, 0), mode="drop")
 
     # --- read output: gathered AFTER the (empty-on-read) scatter ---
     vals = jnp.where((is_read & live)[:, None],
@@ -335,11 +345,6 @@ def apply_op(cfg: PoolConfig, state: Dict, op, obj_ids: jax.Array,
     return state, vals
 
 
-def _zero_values(cfg: PoolConfig, obj_ids: jax.Array) -> jax.Array:
-    return jnp.zeros((obj_ids.shape[0], cfg.slot_words),
-                     jnp.dtype(cfg.dtype))
-
-
 def alloc(cfg: PoolConfig, state: Dict, obj_ids: jax.Array,
           values: jax.Array) -> Dict:
     """Allocate `obj_ids` [k] (see `apply_op`: NEW->COLD->HOT spill off
@@ -352,8 +357,7 @@ def read(cfg: PoolConfig, state: Dict, obj_ids: jax.Array
          ) -> Tuple[jax.Array, Dict]:
     """Gather object payloads for `obj_ids` [k] (−1 entries return zeros).
     This is the paper's pointer dereference — see `apply_op`."""
-    state, vals = apply_op(cfg, state, OP_READ, obj_ids,
-                           _zero_values(cfg, obj_ids))
+    state, vals = apply_op(cfg, state, OP_READ, obj_ids)
     return vals, state
 
 
@@ -366,9 +370,8 @@ def write(cfg: PoolConfig, state: Dict, obj_ids: jax.Array,
 
 def free(cfg: PoolConfig, state: Dict, obj_ids: jax.Array) -> Dict:
     """Release objects (slot returns to its region's free ring) — see
-    `apply_op`."""
-    state, _ = apply_op(cfg, state, OP_FREE, obj_ids,
-                        _zero_values(cfg, obj_ids))
+    `apply_op`. A free moves no payload: `data` is left as it is."""
+    state, _ = apply_op(cfg, state, OP_FREE, obj_ids)
     return state
 
 

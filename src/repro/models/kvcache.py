@@ -207,12 +207,13 @@ def free_lanes(cfg: KVCacheConfig, state: Dict, lanes: jax.Array) -> Dict:
     The release is ONE batched `pool.free` over every (layer, block)
     object id the lane could own — K = layers * batch * max_blocks ids,
     the O(K) free-ring path (slots push back onto their region's rings,
-    `sb_occ` decrements, `slot_ref`/table words clear); ids the lane
-    never allocated are dead and dropped by the op, so partially-filled
-    lanes free exactly their live blocks. The lane's block-table row
-    resets to -1, its pos to 0, and its active bit clears — the freed
-    cold blocks are now the fragmentation the collector must tidy so
-    the backend can reclaim their superblocks."""
+    `sb_occ` decrements, `slot_ref`/table words clear; no payload, so
+    the pool's `data` is not written); ids the lane never allocated are
+    dead and dropped by the op, so partially-filled lanes free exactly
+    their live blocks. The lane's block-table row resets to -1, its pos
+    to 0, and its active bit clears — the freed cold blocks are now the
+    fragmentation the collector must tidy so the backend can reclaim
+    their superblocks."""
     pcfg = cfg.pool_config()
     li = jnp.arange(cfg.num_layers, dtype=jnp.int32)[:, None, None]
     bi = jnp.arange(cfg.batch, dtype=jnp.int32)[None, :, None]

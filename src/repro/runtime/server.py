@@ -701,7 +701,10 @@ class Server:
         geometry-only, so benchmarks and multi-request drivers restart
         instantly. `active=False` starts every lane empty (the
         continuous-batching driver admits lanes through window
-        events)."""
+        events). The old state is dropped before the new pool is
+        allocated, so the device never holds two pools at once (`serve`
+        resets at every call)."""
+        self.state = None
         self.state = kvc.init(self.kv_cfg, backend=self.backend,
                               active=active)
         if self.model.cfg.held_experts:

@@ -14,11 +14,15 @@ The load-bearing contracts:
   * `generate`'s sampling params are live: greedy stays bit-identical
     to the pre-sampler path, `greedy=False` without a key refuses
     instead of silently decoding greedily."""
+import weakref
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import collector as col
+from repro.core import pool as pl
 from repro.models import kvcache as kvc
 from repro.models.model import build
 from repro.runtime.server import Completion, Request, Server, ServerConfig
@@ -87,6 +91,101 @@ def test_free_lanes_returns_slots_to_rings():
     out, _ = kvc.attend(srv.kv_cfg, state2, 0, q)
     assert bool(jnp.all(out[0] == 0)), "inactive lane leaked KV data"
     assert bool(jnp.any(out[1] != 0))
+
+
+_FREE_POOLS = {
+    "flat": pl.make_config(max_objects=64, slot_words=4, sb_slots=8,
+                           page_slots=4, slack=2.0),
+    # a KV slot's tiled row: [K/V, kv head, block tokens, head dim]
+    "tiled": pl.make_config(max_objects=64, slot_words=2 * 2 * 4 * 8,
+                            sb_slots=8, page_slots=4, slack=2.0,
+                            dtype="bfloat16", slot_shape=(2, 2, 4, 8)),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(_FREE_POOLS))
+def test_free_matches_traced_op_path(geometry):
+    """`pool.free` writes no payload, yet leaves every pool leaf exactly
+    as the engine's traced-op path does (`apply_op` with a traced
+    OP_FREE and a zero payload): data with its scratch row, table,
+    owners, rings, occupancy and referenced bits. The freed batch holds
+    live, duplicated, dead and padding ids on a churned pool."""
+    cfg = _FREE_POOLS[geometry]
+    rng = np.random.default_rng(11)
+    state = pl.init(cfg)
+    ids = jnp.arange(48, dtype=jnp.int32)
+    vals = jnp.asarray(rng.standard_normal((48, cfg.slot_words)),
+                       jnp.dtype(cfg.dtype))
+    state = pl.alloc(cfg, state, ids, vals)
+    state = pl.free(cfg, state, jnp.asarray([1, 4, 9, 16, 25], jnp.int32))
+    state = pl.alloc(cfg, state, jnp.asarray([4, 50, 51], jnp.int32),
+                     vals[:3])
+    state, _ = col.collect(cfg, col.CollectorConfig(), state)
+    _, state = pl.read(cfg, state, jnp.asarray([2, 3, 50], jnp.int32))
+
+    # live (some twice), already-freed (dead), never-allocated, padding
+    batch = jnp.asarray([3, 7, 7, 1, 50, 60, -1, 3, 20, 9, 33, -1],
+                        jnp.int32)
+    got = jax.jit(lambda s, i: pl.free(cfg, s, i))(state, batch)
+    want = jax.jit(lambda s, op, i, v: pl.apply_op(cfg, s, op, i, v)[0])(
+        state, jnp.asarray(pl.OP_FREE, jnp.int32), batch,
+        jnp.zeros((batch.shape[0], cfg.slot_words), jnp.dtype(cfg.dtype)))
+
+    flat_got, tree_got = jax.tree.flatten_with_path(got)
+    flat_want, tree_want = jax.tree.flatten_with_path(want)
+    assert tree_got == tree_want
+    for (path, a), (_, b) in zip(flat_got, flat_want):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), \
+            f"{jax.tree_util.keystr(path)} differs from the traced path"
+    assert np.array_equal(np.asarray(got["data"]),
+                          np.asarray(state["data"])), "free moved data"
+    assert not np.any(np.asarray(got["data"][cfg.n_slots])), \
+        "scratch row is not zero"
+    assert int(jnp.sum(got["free_count"])) == \
+        int(jnp.sum(state["free_count"])) + 5
+    check_freelist(got, cfg=cfg)
+
+
+def test_free_lanes_builds_no_payload():
+    """`free_lanes` at DeepSeek-V2-Lite's serving geometry (27 layers,
+    64 lanes of 64 blocks, latent slots of one 576-wide K=V head) needs
+    almost no scratch memory: the free carries no payload, so no
+    K x slot_words zero block is built and scattered onto the scratch
+    row. Compiled from abstract shapes; nothing of the pool is
+    allocated."""
+    cfg = kvc.KVCacheConfig(num_layers=27, batch=64, max_blocks=64,
+                            block_tokens=16, num_kv_heads=1, head_dim=576,
+                            v_dim=512, dtype="bfloat16")
+    state = jax.eval_shape(lambda: kvc.init(cfg, active=False))
+    lanes = jax.ShapeDtypeStruct((cfg.batch,), jnp.bool_)
+    compiled = jax.jit(lambda s, ln: kvc.free_lanes(cfg, s, ln)).lower(
+        state, lanes).compile()
+    data = state["pool"]["data"]
+    data_bytes = data.size * data.dtype.itemsize
+    assert data_bytes > 3 * 10 ** 9
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < data_bytes // 100, \
+        f"free_lanes temp {temp} B against {data_bytes} B of pool data"
+
+
+def test_reset_releases_the_old_pool_first(monkeypatch):
+    """`serve` resets the server at every call: the previous pool must be
+    unreferenced before the fresh one is allocated, or the device holds
+    two pools at once beside the weights."""
+    m, params = _model()
+    srv = Server(m, ServerConfig(**KW))
+    srv.serve(params, [Request(prompt=_prompt(3, 4), max_new=2)])
+    old = weakref.ref(srv.state["pool"]["data"])
+    seen = []
+    init = kvc.init
+
+    def spy(*args, **kwargs):
+        seen.append(old() is None)
+        return init(*args, **kwargs)
+
+    monkeypatch.setattr(kvc, "init", spy)
+    srv.serve(params, [Request(prompt=_prompt(3, 5), max_new=2)])
+    assert seen == [True], "the old pool was alive when the new one was made"
 
 
 def test_serve_one_dispatch_per_window_and_drains_pool():
